@@ -1,6 +1,8 @@
 """Benchmark harness entry point: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows. Accuracy tables emit their
+Prints ``name,us_per_call,derived`` CSV rows; a benchmark that raises
+prints an ``ERROR`` row and its traceback, the rest still run, and the
+harness exits non-zero. Accuracy tables emit their
 metric in the ``derived`` column with us_per_call as the wall time of the
 full table evaluation. The serving benchmark additionally persists a
 machine-readable ``BENCH_serve.json`` (tok/s, speedups, occupancy, host-sync
@@ -11,7 +13,9 @@ from __future__ import annotations
 
 import argparse
 import functools
+import sys
 import time
+import traceback
 
 
 def _emit(name, us, derived):
@@ -59,16 +63,21 @@ def main() -> None:
         selected = {k: benches[k] for k in keys}
 
     print("name,us_per_call,derived")
+    failed = []
     for bench_name, fn in selected.items():
         t0 = time.perf_counter()
         try:
             rows = fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
             _emit(f"{bench_name}.ERROR", 0.0, repr(e)[:80])
+            traceback.print_exc()
+            failed.append(bench_name)
             continue
         dt_us = (time.perf_counter() - t0) * 1e6
         for row_name, value in rows:
             _emit(f"{bench_name}.{row_name}", dt_us / max(len(rows), 1), value)
+    if failed:
+        sys.exit(f"benchmark(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -15,8 +15,8 @@ Five ops (taxonomy B.12 — W8A8 / weight-only / dynamic-quant):
   * ``kv_attention``  — single-token decode attention with the int8 KV cache
                         dequantized in VMEM (one HBM pass over the cache —
                         the EXPERIMENTS §Perf C5 roofline term, fused).
-                        Handles GQA (q heads / kv heads via in-kernel
-                        reshape) and ragged per-slot lengths through
+                        Handles GQA (each kv head's q-head group as one
+                        2-D matmul) and ragged per-slot lengths through
                         zero-scale masking.
   * ``fused_decode``  — the decode megakernel: append-quantize + int8
                         attention (+ optional W8A8 quantize-out epilogue)
@@ -26,7 +26,8 @@ Five ops (taxonomy B.12 — W8A8 / weight-only / dynamic-quant):
 
 Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (public
 wrapper), ref.py (pure-jnp oracle). Kernels VALIDATE in interpret mode on
-CPU; TPU is the compile target.
+CPU, compile for a described TPU v5e in tests/test_tpu_compile.py, and run
+on the chip in chip_smoke.py.
 
 Backend selection, padding policy, and the op registry all live in
 ``dispatch.py`` — every op registers its pallas/xla/interpret/ref tiers

@@ -3,9 +3,10 @@
 Two oracles with different jobs:
 
   * ``kv_attention_ref`` — mirrors the Pallas kernel **block for block**
-    (same block order, same fp32 op sequence, same zero-scale masking), so
-    the interpret-mode kernel must match it *bit-exactly*: any divergence is
-    a BlockSpec/grid/scratch bug, not numerics. The property tests pin this
+    (same block order, the kernel's own per-head ``attend_block``, same
+    zero-scale masking), so the interpret-mode kernel must match it
+    *bit-exactly*: any divergence is a BlockSpec/grid/scratch bug, not
+    numerics. The property tests pin this
     over ragged lengths, GQA ratios, and non-multiple-of-blk S.
   * ``kv_attention_xla`` — the production XLA backend for non-TPU serving:
     plain masked softmax with the per-token/per-head scales folded in at
@@ -48,7 +49,11 @@ def kv_attention_ref(
     *,
     blk: int = 512,
 ) -> jnp.ndarray:
-    """Blocked online-softmax oracle — the kernel's math in pure jnp."""
+    """Blocked online-softmax oracle — the kernel's math in pure jnp: the
+    kernel's own ``attend_block`` per (batch row, kv head), scanned over the
+    blocks in grid order, on the same operand shapes the kernel sees."""
+    from .kernel import attend_block, finish, head_major, lane_scales
+
     B, S, Hkv, hd = k_q.shape
     Hq = q.shape[1]
     group = Hq // Hkv
@@ -56,36 +61,30 @@ def kv_attention_ref(
     n_blk = k_q.shape[1] // blk_e
     scale = 1.0 / (hd ** 0.5)
 
-    qg = q.astype(jnp.float32).reshape(B, Hkv, group, hd)
-    # [n_blk, B, blk, ...] block streams, scanned in the kernel's grid order
-    def blocks(a):
-        return a.reshape(B, n_blk, blk_e, *a.shape[2:]).transpose(
-            1, 0, *range(2, a.ndim + 1))
+    def payload(a):     # [B, S, Hkv, hd] → [B·Hkv, n_blk, blk, hd]
+        return a.transpose(0, 2, 1, 3).reshape(B * Hkv, n_blk, blk_e, hd)
 
-    def body(carry, inp):
-        m, l, acc = carry                       # [B, Hq], [B, Hq], [B, Hq, hd]
-        kq_b, ks_b, vq_b, vs_b = inp
-        ks_b = ks_b.astype(jnp.float32)
-        k = kq_b.astype(jnp.float32) * ks_b[..., None]      # [B, blk, Hkv, hd]
-        s = jnp.einsum("bngd,bknd->bngk", qg, k) * scale    # [B, Hkv, G, blk]
-        s = jnp.where((ks_b > 0).transpose(0, 2, 1)[:, :, None, :], s, _NEG)
-        s = s.reshape(B, Hq, -1)
-        m_new = jnp.maximum(m, jnp.max(s, -1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, -1)
-        v = vq_b.astype(jnp.float32) * vs_b.astype(jnp.float32)[..., None]
-        pv = jnp.einsum("bngk,bknd->bngd", p.reshape(B, Hkv, group, -1), v)
-        acc = acc * corr[..., None] + pv.reshape(B, Hq, hd)
-        return (m_new, l, acc), None
+    def scales(a):      # [B, S, Hkv] → [B·Hkv, n_blk, 1, blk]
+        return lane_scales(a).reshape(B * Hkv, n_blk, 1, blk_e)
 
-    m0 = jnp.full((B, Hq), _NEG, jnp.float32)
-    l0 = jnp.zeros((B, Hq), jnp.float32)
-    acc0 = jnp.zeros((B, Hq, hd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, acc0), (blocks(k_q), blocks(k_s), blocks(v_q), blocks(v_s))
-    )
-    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(out_dtype)
+    def head(args):
+        q1, kq1, ks1, vq1, vs1 = args
+
+        def body(carry, blocks):
+            return attend_block(q1, *blocks, *carry, scale=scale), None
+
+        gp = q1.shape[0]
+        init = (jnp.full((gp, 1), _NEG, jnp.float32),
+                jnp.zeros((gp, 1), jnp.float32),
+                jnp.zeros((gp, hd), jnp.float32))
+        (m, l, acc), _ = jax.lax.scan(body, init, (kq1, ks1, vq1, vs1))
+        return finish(acc, l)
+
+    qh = head_major(q.astype(jnp.float32), Hkv)
+    out = jax.lax.map(head, (qh.reshape(B * Hkv, -1, hd), payload(k_q),
+                             scales(k_s), payload(v_q), scales(v_s)))
+    out = out.reshape(B, Hkv, -1, hd)[:, :, :group]
+    return out.reshape(B, Hq, hd).astype(out_dtype)
 
 
 def kv_attention_xla(

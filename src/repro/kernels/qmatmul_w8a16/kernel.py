@@ -7,6 +7,10 @@ via a VMEM scratch; bias/scale epilogue on the last K step.
 
 Decode blocks default to (bm, bn, bk) = (8, 512, 1024): M is the (small)
 batch; wide N amortizes the per-block scale/bias loads.
+
+Per-column scale and bias enter as lane-dense ``[1, N]`` rows and the
+quantize-out row scale leaves as an ``[M, 1]`` column: Mosaic refuses
+rank-1 blocks whose tiling differs from XLA's layout of the same vector.
 """
 from __future__ import annotations
 
@@ -15,28 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    _SCRATCH = lambda bm, bn: [pltpu.VMEM((bm, bn), jnp.float32)]
-    _PARAMS = lambda: dict(
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    )
-    _PARAMS_MK = lambda: dict(
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    )
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = lambda bm, bn: [jax.ShapeDtypeStruct((bm, bn), jnp.float32)]
-    _PARAMS = lambda: {}
-    _PARAMS_MK = lambda: {}
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(a_ref, w_ref, sw_ref, bias_ref, o_ref, acc_ref, *, n_k):
@@ -54,7 +37,7 @@ def _kernel(a_ref, w_ref, sw_ref, bias_ref, o_ref, acc_ref, *, n_k):
 
     @pl.when(k == n_k - 1)
     def _epilogue():
-        out = acc_ref[...] * sw_ref[...][None, :] + bias_ref[...][None, :]
+        out = acc_ref[...] * sw_ref[...] + bias_ref[...]
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -84,15 +67,17 @@ def qmatmul_w8a16_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=_SCRATCH(bm, bn),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **_PARAMS(),
-    )(a, w_q, w_scale.astype(jnp.float32), bias.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(a, w_q, w_scale.astype(jnp.float32).reshape(1, N),
+      bias.astype(jnp.float32).reshape(1, N))
 
 
 def _kernel_q8(a_ref, w_ref, sw_ref, bias_ref, q_ref, s_ref, acc_ref,
@@ -113,10 +98,10 @@ def _kernel_q8(a_ref, w_ref, sw_ref, bias_ref, q_ref, s_ref, acc_ref,
 
     @pl.when(k == n_k - 1)
     def _epilogue():
-        out = acc_ref[...] * sw_ref[...][None, :] + bias_ref[...][None, :]
-        amax = jnp.max(jnp.abs(out), axis=-1)
+        out = acc_ref[...] * sw_ref[...] + bias_ref[...]
+        amax = jnp.max(jnp.abs(out), axis=-1, keepdims=True)
         scale = jnp.maximum(amax, 1e-8) / qmax
-        q = jnp.clip(jnp.round(out / scale[:, None]), -qmax - 1, qmax)
+        q = jnp.clip(jnp.round(out / scale), -qmax - 1, qmax)
         q_ref[...] = q.astype(jnp.int8)
         s_ref[...] = scale
 
@@ -143,24 +128,27 @@ def qmatmul_w8a16_q8_pallas(
     n_k = K // bk
     qmax = 2 ** (bits - 1) - 1
     grid = (M // bm, n_k)
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         functools.partial(_kernel_q8, n_k=n_k, qmax=qmax),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
             pl.BlockSpec((bk, N), lambda i, k: (k, 0)),
-            pl.BlockSpec((N,), lambda i, k: (0,)),
-            pl.BlockSpec((N,), lambda i, k: (0,)),
+            pl.BlockSpec((1, N), lambda i, k: (0, 0)),
+            pl.BlockSpec((1, N), lambda i, k: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bm, N), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm,), lambda i, k: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, k: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), jnp.int8),
-            jax.ShapeDtypeStruct((M,), jnp.float32),
+            jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
-        scratch_shapes=_SCRATCH(bm, N),
+        scratch_shapes=[pltpu.VMEM((bm, N), jnp.float32)],
         interpret=interpret,
-        **_PARAMS_MK(),
-    )(a, w_q, w_scale.astype(jnp.float32), bias.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(a, w_q, w_scale.astype(jnp.float32).reshape(1, N),
+      bias.astype(jnp.float32).reshape(1, N))
+    return q, s[:, 0]

@@ -19,28 +19,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only submodule; absent on CPU wheels — interpret mode doesn't need it
-    from jax.experimental.pallas import tpu as pltpu
 
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    _SCRATCH = lambda bm, bn: [pltpu.VMEM((bm, bn), jnp.int32)]
-    _PARAMS = lambda: dict(
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    )
-    _PARAMS_MK = lambda: dict(
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    )
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = lambda bm, bn: [jax.ShapeDtypeStruct((bm, bn), jnp.int32)]
-    _PARAMS = lambda: {}
-    _PARAMS_MK = lambda: {}
+def _scale_blocks(a_scale, w_scale, bias):
+    """Rank-1 scale/bias vectors → the 2-D blocks Mosaic lays out like XLA:
+    per-row activation scales as an ``[M, 1]`` column, per-column weight
+    scales and bias as lane-dense ``[1, N]`` rows."""
+    f32 = jnp.float32
+    return (a_scale.astype(f32).reshape(-1, 1),
+            w_scale.astype(f32).reshape(1, -1), bias.astype(f32).reshape(1, -1))
 
 
 def _kernel(a_ref, w_ref, sa_ref, sw_ref, bias_ref, o_ref, acc_ref, *, n_k):
@@ -60,8 +48,8 @@ def _kernel(a_ref, w_ref, sa_ref, sw_ref, bias_ref, o_ref, acc_ref, *, n_k):
     @pl.when(k == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        out = acc * sa_ref[...][:, None] * sw_ref[...][None, :]
-        out = out + bias_ref[...][None, :]
+        out = acc * sa_ref[...] * sw_ref[...]
+        out = out + bias_ref[...]
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -93,17 +81,17 @@ def qmatmul_w8a8_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=_SCRATCH(bm, bn),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        **_PARAMS(),
-    )(a_q, w_q, a_scale.astype(jnp.float32), w_scale.astype(jnp.float32),
-      bias.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(a_q, w_q, *_scale_blocks(a_scale, w_scale, bias))
 
 
 def _kernel_q8(a_ref, w_ref, sa_ref, sw_ref, bias_ref, q_ref, s_ref, acc_ref,
@@ -127,11 +115,11 @@ def _kernel_q8(a_ref, w_ref, sa_ref, sw_ref, bias_ref, q_ref, s_ref, acc_ref,
     @pl.when(k == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        out = acc * sa_ref[...][:, None] * sw_ref[...][None, :]
-        out = out + bias_ref[...][None, :]
-        amax = jnp.max(jnp.abs(out), axis=-1)
+        out = acc * sa_ref[...] * sw_ref[...]
+        out = out + bias_ref[...]
+        amax = jnp.max(jnp.abs(out), axis=-1, keepdims=True)
         scale = jnp.maximum(amax, 1e-8) / qmax
-        q = jnp.clip(jnp.round(out / scale[:, None]), -qmax - 1, qmax)
+        q = jnp.clip(jnp.round(out / scale), -qmax - 1, qmax)
         q_ref[...] = q.astype(jnp.int8)
         s_ref[...] = scale
 
@@ -161,26 +149,27 @@ def qmatmul_w8a8_q8_pallas(
     n_k = K // bk
     qmax = 2 ** (bits - 1) - 1
     grid = (M // bm, n_k)
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         functools.partial(_kernel_q8, n_k=n_k, qmax=qmax),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
             pl.BlockSpec((bk, N), lambda i, k: (k, 0)),
-            pl.BlockSpec((bm,), lambda i, k: (i,)),
-            pl.BlockSpec((N,), lambda i, k: (0,)),
-            pl.BlockSpec((N,), lambda i, k: (0,)),
+            pl.BlockSpec((bm, 1), lambda i, k: (i, 0)),
+            pl.BlockSpec((1, N), lambda i, k: (0, 0)),
+            pl.BlockSpec((1, N), lambda i, k: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bm, N), lambda i, k: (i, 0)),
-            pl.BlockSpec((bm,), lambda i, k: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, k: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), jnp.int8),
-            jax.ShapeDtypeStruct((M,), jnp.float32),
+            jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
-        scratch_shapes=_SCRATCH(bm, N),
+        scratch_shapes=[pltpu.VMEM((bm, N), jnp.int32)],
         interpret=interpret,
-        **_PARAMS_MK(),
-    )(a_q, w_q, a_scale.astype(jnp.float32), w_scale.astype(jnp.float32),
-      bias.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(a_q, w_q, *_scale_blocks(a_scale, w_scale, bias))
+    return q, s[:, 0]

@@ -4,6 +4,8 @@ One pass over the activation row in VMEM: reduce |x|max across K, derive the
 scale, round — the quantize stage of the dynamic W8A8 path costs a single
 HBM read + int8 write instead of (reduce pass + scale pass).
 Block (bm, K): whole rows resident (K ≤ 8k ⇒ ≤ 4 MiB fp32 at bm = 128).
+The row scale leaves as an ``[M, 1]`` column: Mosaic refuses a rank-1
+``(bm,)`` block whose tiling differs from XLA's layout of the vector.
 """
 from __future__ import annotations
 
@@ -16,9 +18,9 @@ from jax.experimental import pallas as pl
 
 def _kernel(x_ref, q_ref, s_ref, *, qmax):
     x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x), axis=-1)
+    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-8) / qmax
-    q = jnp.clip(jnp.round(x / scale[:, None]), -qmax - 1, qmax)
+    q = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
 
@@ -31,17 +33,18 @@ def quantize_act_pallas(
     assert M % bm == 0
     qmax = 2 ** (bits - 1) - 1
     grid = (M // bm,)
-    return pl.pallas_call(
+    q, s = pl.pallas_call(
         functools.partial(_kernel, qmax=qmax),
         grid=grid,
         in_specs=[pl.BlockSpec((bm, K), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((bm, K), lambda i: (i, 0)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, K), jnp.int8),
-            jax.ShapeDtypeStruct((M,), jnp.float32),
+            jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
+    return q, s[:, 0]

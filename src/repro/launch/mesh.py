@@ -28,9 +28,5 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (TypeError, AttributeError):  # older jax without axis_types/AxisType
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

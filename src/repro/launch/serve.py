@@ -22,8 +22,10 @@ of ``repro.quantize``:
 from __future__ import annotations
 
 import dataclasses
+import os
 import signal
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -57,12 +59,39 @@ def _check_servable(cfg, what):
         )
 
 
+class ServeResults(dict):
+    """``serve``'s return value: the engine's ``{rid: RequestResult}`` map,
+    plus the ``engine`` that served it and the run's wall-clock split —
+    ``setup_seconds`` (quantize or load, engine build, warmup compiles) and
+    ``serve_seconds`` (submission to drain)."""
+
+    def __init__(self, results, engine, setup_seconds, serve_seconds):
+        super().__init__(results)
+        self.engine = engine
+        self.setup_seconds = setup_seconds
+        self.serve_seconds = serve_seconds
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory and
+    return it: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it
+    itself), else ``.jax_cache`` at the root of this checkout. Entry points
+    call this; importing the package never touches the cache."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def serve(config: ServeConfig):
     """Quantize (or ``load``) a model and serve it — the whole driver behind
     ``python -m repro.launch.serve``, callable as ``repro.serve(config)``.
-    Returns the engine's ``{rid: RequestResult}`` map. Invalid or
+    Returns a ``ServeResults``: the engine's ``{rid: RequestResult}`` map
+    (with the engine and the set-up/serve wall times attached). Invalid or
     conflicting configuration raises ``ServeConfigError``."""
     config = dataclasses.replace(config).validate()
+    t_setup = time.time()
     cli_mesh = config.mesh        # pre-merge: distinguishes --mesh vs artifact
 
     qm = None
@@ -95,7 +124,9 @@ def serve(config: ServeConfig):
                 # engine serves any recipe sharded either way
                 if config.mesh and f"{recipe}-tp" in BUILTIN_RECIPES:
                     recipe = f"{recipe}-tp"
+            t0 = time.time()
             qm = quantize(model, recipe=recipe)
+            print(f"quantize: recipe {recipe!r} in {time.time() - t0:.1f} s")
             if (config.kv_bits is not None
                     and qm.cfg.kv_cache_bits != config.kv_bits):
                 # an explicit --recipe may not carry a kv_cache stage: fold
@@ -226,6 +257,7 @@ def serve(config: ServeConfig):
         t0 = time.time()
         engine.warmup()
         print(f"warmup: compiled serving shapes in {time.time() - t0:.1f} s")
+    setup_s = time.time() - t_setup
 
     # SIGTERM → graceful drain: stop admitting, finish in-flight + parked,
     # report, exit 0 (modeled on runtime.fault_tolerance.FaultTolerantLoop).
@@ -328,6 +360,7 @@ def serve(config: ServeConfig):
                 f"{engine.stats['straggler_threshold']:g}x step EMA)")
         print("results by status: " +
               ", ".join(f"{k}={v}" for k, v in sorted(by_status.items())))
+    results = ServeResults(results, engine, setup_s, dt)
     if not results:
         return results
     first = results[min(results)]
@@ -345,4 +378,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

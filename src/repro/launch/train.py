@@ -40,7 +40,8 @@ def main():
 
     cfg = get_config(args.arch, smoke=args.smoke)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = jax.make_mesh((n_dev, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     model, train_step = make_train_step(cfg, lr_cfg={
         "peak_lr": 1e-3, "warmup": 20, "total": args.steps})
     configure_sharding_hints(cfg, mesh)

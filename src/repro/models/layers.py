@@ -19,22 +19,6 @@ import jax.numpy as jnp
 
 from .config import ModelConfig
 
-# shard_map moved to the jax namespace and check_rep → check_vma across
-# releases — independently (0.5/0.6 expose jax.shard_map but still take
-# check_rep), so resolve the location and the kwarg name separately.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
 
 def scan_layers(body, carry, xs, unroll: bool = False):
     """lax.scan over stacked layer params, or an unrolled python loop when
@@ -55,14 +39,15 @@ def scan_layers(body, carry, xs, unroll: bool = False):
     return carry, ys_stacked
 
 
-def linear(x, w, b=None):
+def linear(x, w, b=None, tp=None):
     """y = x @ w + b. Dispatches on weight type: an int8 ``QTensor`` routes
     through the Pallas W8A16/W8A8 kernels (repro.quantized) — the same model
-    code serves fp and quantized."""
+    code serves fp and quantized. ``tp`` ("col" | "row") names the weight's
+    tensor-parallel role, which the kernels need under a serve mesh."""
     if type(w).__name__ == "QTensor":
         from ..quantized.qtensor import qtensor_matmul
 
-        return qtensor_matmul(x, w, b)
+        return qtensor_matmul(x, w, b, tp=tp)
     y = x @ w
     if b is not None:
         y = y + b
@@ -74,7 +59,7 @@ def _all_w8a8(*ws) -> bool:
                for w in ws)
 
 
-def _shared_linears(x, wbs):
+def _shared_linears(x, wbs, tps):
     """Several W8A8 projections reading the SAME activation (the qkv trio,
     the GLU gate/up pair) share one ``quantize_act`` dispatch. Per-row
     dynamic quantization depends only on the row, so each output is bitwise
@@ -82,8 +67,9 @@ def _shared_linears(x, wbs):
     from ..quantized.qtensor import qtensor_matmul_prequant, quantize_input
 
     a_q, a_s, lead = quantize_input(x)
-    return [qtensor_matmul_prequant(a_q, a_s, w, b, lead, out_dtype=x.dtype)
-            for w, b in wbs]
+    return [qtensor_matmul_prequant(a_q, a_s, w, b, lead, out_dtype=x.dtype,
+                                    tp=tp)
+            for (w, b), tp in zip(wbs, tps)]
 
 
 # --------------------------------------------------------------------------
@@ -183,44 +169,66 @@ def set_serve_mesh(mesh=None, *, dp=("data",), model="model") -> dict:
     return prev
 
 
-def _serve_decode_partition(nq: int, nkv: int, B: int):
-    """(mesh, dp_spec, model_axis) when the decode attention can shard_map
-    head-locally — the model axis must divide BOTH head counts (a shard owns
-    whole GQA groups) — else None. ``dp_spec`` degrades to replication when
-    the slot count doesn't divide the data axis."""
+def serve_mesh_axes(rows: int):
+    """(mesh, row_spec, model_axis, model_n) under an armed serve mesh, else
+    None. ``row_spec`` shards a leading batch/slot axis of ``rows`` over the
+    data axes, degrading to replication when they don't divide it."""
     mesh = _SERVE_MESH["mesh"]
     if mesh is None:
         return None
     sizes = dict(mesh.shape)
     mdl = _SERVE_MESH["model"]
-    m_n = sizes.get(mdl, 1)
-    if m_n <= 1 or nq % m_n or nkv % m_n:
-        return None
     dp = tuple(a for a in _SERVE_MESH["dp"] if a in sizes)
     dp_n = 1
     for a in dp:
         dp_n *= sizes[a]
-    dp_spec = dp if (dp_n > 1 and B % dp_n == 0) else None
-    return mesh, dp_spec, mdl
+    row_spec = dp if (dp_n > 1 and rows % dp_n == 0) else None
+    return mesh, row_spec, mdl, sizes.get(mdl, 1)
 
 
-def _fused_decode_tp(part, q1, cache, k_new, v_new, idx, valid, out_dtype):
-    """shard_map the fused decode attention over (data, model): q heads and
-    the KV cache's head axis live on "model", slots on "data". Attention is
-    head-local, so the body emits no collectives — the -tp serving contracts
-    pin the decode collective budget at the same level as single-device."""
+def _head_tp(n_heads: int, role: str):
+    """A projection's TP role when the serve mesh's model axis splits its
+    heads whole (the serve placement rule of ``sharding.partition``), else
+    None (replicated)."""
+    ax = serve_mesh_axes(1)
+    return role if ax is None or n_heads % ax[3] == 0 else None
+
+
+def _serve_decode_partition(nq: int, nkv: int, B: int, kernel: bool):
+    """(mesh, dp_spec, model_axis) when the decode attention runs under
+    shard_map — head-locally when the model axis divides BOTH head counts
+    (a shard owns whole GQA groups). Otherwise the XLA tier leaves it to
+    GSPMD (None), while a Pallas tier (``kernel``), which GSPMD cannot
+    partition, still shard_maps over the slots with heads replicated
+    (model_axis None). ``dp_spec`` degrades to replication when the slot
+    count doesn't divide the data axis."""
+    ax = serve_mesh_axes(B)
+    if ax is None:
+        return None
+    mesh, dp_spec, mdl, m_n = ax
+    if m_n > 1 and nq % m_n == 0 and nkv % m_n == 0:
+        return mesh, dp_spec, mdl
+    return (mesh, dp_spec, None) if kernel else None
+
+
+def _fused_decode_tp(part, q1, cache, k_new, v_new, idx, valid, out_dtype,
+                     backend, op):
+    """shard_map the decode attention ``op`` (``fused_decode``, or the
+    stepwise ``kv_attention_decode`` with fusion off) over (data, model): q
+    heads and the KV cache's head axis live on "model", slots on "data".
+    Attention is head-local, so the body emits no collectives — the -tp
+    serving contracts pin the decode collective budget at the same level as
+    single-device."""
     from jax.sharding import PartitionSpec as P
-
-    from ..kernels.fused_decode.ops import fused_decode
 
     mesh, dp, mdl = part
     per_slot = idx.ndim == 2
 
     def local_fn(q1, ck, cks, cv, cvs, kn, vn, idx, valid):
-        return fused_decode(q1, ck, cks, cv, cvs, kn, vn, idx,
-                            valid=valid, out_dtype=out_dtype)
+        return op(q1, ck, cks, cv, cvs, kn, vn, idx, valid=valid,
+                  out_dtype=out_dtype, backend=backend)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -237,7 +245,7 @@ def _fused_decode_tp(part, q1, cache, k_new, v_new, idx, valid, out_dtype):
         out_specs=(P(dp, mdl, None),
                    (P(dp, None, mdl, None), P(dp, None, mdl),
                     P(dp, None, mdl, None), P(dp, None, mdl))),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
     return fn(q1, cache["k"], cache["k_scale"], cache["v"], cache["v_scale"],
               k_new, v_new, idx, valid)
@@ -424,14 +432,15 @@ def attention_block(
         stats["attn_in"] = jnp.mean(x.reshape(-1, D), 0)
 
     src = x if kv_input is None else kv_input
+    tp_q, tp_kv = _head_tp(nq, "col"), _head_tp(nkv, "col")
     if kv_input is None and _all_w8a8(p["wq"], p["wk"], p["wv"]):
         q, k, v = _shared_linears(
             x, [(p["wq"], p.get("bq")), (p["wk"], p.get("bk")),
-                (p["wv"], p.get("bv"))])
+                (p["wv"], p.get("bv"))], (tp_q, tp_kv, tp_kv))
     else:
-        q = linear(x, p["wq"], p.get("bq"))
-        k = linear(src, p["wk"], p.get("bk"))
-        v = linear(src, p["wv"], p.get("bv"))
+        q = linear(x, p["wq"], p.get("bq"), tp=tp_q)
+        k = linear(src, p["wk"], p.get("bk"), tp=tp_kv)
+        v = linear(src, p["wv"], p.get("bv"), tp=tp_kv)
     Tk_in = src.shape[1]
     q = q.reshape(B, T, nq, hd)
     k = k.reshape(B, Tk_in, nkv, hd)
@@ -509,25 +518,28 @@ def attention_block(
                 verr = cache.get("v_err")
                 backend = serving_backend(pallas_ok=verr is None)
                 part = (None if verr is not None
-                        else _serve_decode_partition(nq, nkv, B))
+                        else _serve_decode_partition(
+                            nq, nkv, B, kernel=backend != "xla"))
                 # the W8A8 wo projection reads the kernel's quantize-out
                 # epilogue directly (int8 + per-row scale): the standalone
                 # quantize_act dispatch between attention and wo is gone
                 want_q8 = (_all_w8a8(p["wo"]) and verr is None
                            and part is None)
-                if not fusion_enabled():
+                if part is not None:
+                    # TP: shard_map over (data, model) — head-local, zero
+                    # collectives, no quantize-out (the row scale is a
+                    # cross-head reduction)
+                    out, leaves = _fused_decode_tp(
+                        part, q[:, 0], cache, k, v, idx, valid, x.dtype,
+                        backend, fused_decode if fusion_enabled()
+                        else kv_attention_decode)
+                elif not fusion_enabled():
                     out, leaves = kv_attention_decode(
                         q[:, 0], cache["k"], cache["k_scale"], cache["v"],
                         cache["v_scale"], k, v, idx, valid=valid,
                         out_dtype=x.dtype, backend=backend,
                         cache_verr=verr,
                     )
-                elif part is not None:
-                    # TP: shard_map over (data, model) — head-local, zero
-                    # collectives, no quantize-out (the row scale is a
-                    # cross-head reduction)
-                    out, leaves = _fused_decode_tp(
-                        part, q[:, 0], cache, k, v, idx, valid, x.dtype)
                 else:
                     res, leaves = fused_decode(
                         q[:, 0], cache["k"], cache["k_scale"], cache["v"],
@@ -587,7 +599,7 @@ def attention_block(
                 attn_q8[0], attn_q8[1], p["wo"], p.get("bo"), (B, T),
                 out_dtype=x.dtype)
         else:
-            out = linear(attn, p["wo"], p.get("bo"))
+            out = linear(attn, p["wo"], p.get("bo"), tp=_head_tp(nq, "row"))
         return out, new_cache, stats
 
     group = nq // nkv
@@ -619,7 +631,7 @@ def attention_block(
             attn = _wsc(attn, ctx["dp"], None, ctx["model"])
     if capture:
         stats["o_in"] = jnp.mean(attn.reshape(-1, nq * hd), 0)
-    out = linear(attn, p["wo"], p.get("bo"))
+    out = linear(attn, p["wo"], p.get("bo"), tp=_head_tp(nq, "row"))
     return out, new_cache, stats
 
 
@@ -640,16 +652,17 @@ def mlp_block(p: dict, x: jnp.ndarray, act: str, capture: bool = False):
         if _all_w8a8(p["wg"], p["wu"]):
             # gate and up read the same x: one shared quantize dispatch
             g, u = _shared_linears(x, [(p["wg"], p.get("bg")),
-                                       (p["wu"], p.get("bu"))])
+                                       (p["wu"], p.get("bu"))],
+                                   ("col", "col"))
         else:
-            g = linear(x, p["wg"], p.get("bg"))
-            u = linear(x, p["wu"], p.get("bu"))
+            g = linear(x, p["wg"], p.get("bg"), tp="col")
+            u = linear(x, p["wu"], p.get("bu"), tp="col")
         h = _act(act[:-4])(g) * u
     else:
-        h = _act(act)(linear(x, p["wu"], p.get("bu")))
+        h = _act(act)(linear(x, p["wu"], p.get("bu"), tp="col"))
     if capture:
         stats["down_in"] = jnp.mean(h.reshape(-1, h.shape[-1]), 0)
-    return linear(h, p["wd"], p.get("bd")), stats
+    return linear(h, p["wd"], p.get("bd"), tp="row"), stats
 
 
 def moe_block(p: dict, x: jnp.ndarray, cfg: ModelConfig, capture: bool = False):
@@ -804,11 +817,11 @@ def _moe_block_shardmap(p: dict, x: jnp.ndarray, cfg: ModelConfig):
         aux = jax.lax.pmean(jax.lax.pmean(aux, mdl), dp)
         return y, aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(_moe_specs(p, dp, mdl), P(x_batch_spec, None, None)),
         out_specs=(P(x_batch_spec, None, None), P()),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(p, x)
     return y, aux, {}

@@ -5,7 +5,7 @@ fallback (seeded uniform sampling, capped example count) so property tests
 still collect and run green without the dependency.
 """
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 
     HAVE_HYPOTHESIS = True
@@ -51,6 +51,8 @@ except ModuleNotFoundError:
                     getattr(wrapper, "_max_examples", _FALLBACK_EXAMPLES),
                     _FALLBACK_EXAMPLES,
                 )
+                for pinned in getattr(fn, "_examples", ()):
+                    fn(*args, **kwargs, **pinned)
                 rng = random.Random(0)
                 for _ in range(n):
                     drawn = {k: s.draw(rng) for k, s in strategies.items()}
@@ -61,6 +63,13 @@ except ModuleNotFoundError:
             del wrapper.__wrapped__
             wrapper.__signature__ = inspect.Signature()
             return wrapper
+
+        return deco
+
+    def example(**pinned):
+        def deco(fn):
+            fn._examples = [pinned, *getattr(fn, "_examples", ())]
+            return fn
 
         return deco
 

@@ -25,7 +25,8 @@ from repro.models.model import input_specs
 from repro.launch.steps import (
     configure_sharding_hints, make_decode_step, make_train_step, shardings_for)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 out = {}
 for arch in ["qwen2-0.5b", "mixtral-8x22b", "mamba2-2.7b", "whisper-tiny"]:
     cfg = get_config(arch, smoke=True)

@@ -102,6 +102,22 @@ def test_sharded_engine_token_parity(variant, fp32_setup, tp_artifacts, mesh):
         assert len(sharded[r.rid]) == r.max_new_tokens
 
 
+@pytest.mark.parametrize("variant", VARIANTS[1:])
+def test_sharded_kernel_tier_token_parity(variant, fp32_setup, tp_artifacts,
+                                          mesh, monkeypatch):
+    """On a Pallas tier (here its interpret twin) GSPMD cannot partition the
+    kernels, so every one runs per shard under shard_map — column-parallel
+    GEMMs on their own columns, row-parallel ones psum'd, attention per
+    slot shard — and the sharded engine still serves the single-device
+    tokens."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+    model, params, cfg = _setup(variant, fp32_setup, tp_artifacts)
+    trace = _mixed_trace(cfg.vocab_size)
+    single = _tokens(_engine(model, params, cfg), trace)
+    sharded = _tokens(_engine(model, params, cfg, mesh=mesh), trace)
+    assert sharded == single, f"{variant}: per-shard kernels diverged"
+
+
 @pytest.mark.parametrize("variant", ["fp32", "serve-w8a8-kv8-tp"])
 def test_sharded_fast_vs_stepwise_parity(variant, fp32_setup, tp_artifacts,
                                          mesh):

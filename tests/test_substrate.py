@@ -218,10 +218,8 @@ def test_compressed_mean_under_shard_map():
     g = jax.random.normal(jax.random.PRNGKey(1), (64,))
     r = jnp.zeros_like(g)
 
-    from repro.models.layers import _SHARD_MAP_CHECK_KW, _shard_map
-
-    @partial(_shard_map, mesh=mesh, in_specs=(P(), P()),
-             out_specs=(P(), P()), **{_SHARD_MAP_CHECK_KW: False})
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+             out_specs=(P(), P()), check_vma=False)
     def sync(g, r):
         return compressed_mean(g, r, "dp")
 
