@@ -76,6 +76,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..runtime.fault_tolerance import StragglerMonitor
 from .cache_pool import KNOWN_BOOKKEEPING, CachePool
@@ -350,6 +351,7 @@ class ServingEngine:
         self._cancelled: set[int] = set()
         self._inject_bad: set[int] = set()
         self._draining = False
+        self._waited = 0.0          # this step's seconds in _fetch
         # REPRO_POOL_CHECK=1: audit pool bookkeeping after every step
         self._pool_check = os.environ.get("REPRO_POOL_CHECK") == "1"
         self.results: dict[int, RequestResult] = {}
@@ -364,6 +366,11 @@ class ServingEngine:
             # must not grow memory with uptime
             "occupancy_sum": 0.0,
             "engine_steps": 0,
+            # the host's own time in step(): the seconds the calls took
+            # less their waits on device results (``_fetch``), and the
+            # number of calls
+            "step_host_s": 0.0,
+            "step_calls": 0,
             # fault-tolerance counters (the serve report's fault table)
             "preempted": 0,           # in-flight requests parked for pages
             "resumed": 0,             # parked requests re-admitted
@@ -657,11 +664,13 @@ class ServingEngine:
                 f"request {request.rid}: engine is draining — admission "
                 f"is closed"
             )
+        t = time.perf_counter()
         try:
             self.scheduler.submit(request)
         except QueueFull:
             self.stats["shed"] += 1
             raise
+        request.t_submit = t
 
     def set_stream_callbacks(self, on_token=None, on_result=None) -> None:
         """Wire the step-boundary streaming surface (see the class
@@ -783,6 +792,7 @@ class ServingEngine:
             if req is None:
                 return
             self.scheduler.pop_ready(self.clock)
+            req.t_admit = time.perf_counter()
             # fast path: defer the slot's bookkeeping reset into the first
             # jitted prefill chunk (fresh mask) — admission costs 0 dispatches
             slot = pool.allocate(reset=not self.fast)
@@ -830,6 +840,7 @@ class ServingEngine:
                 self._parked.popleft()
             else:
                 self.scheduler.pop_ready(self.clock)
+                req.t_admit = time.perf_counter()
             slot = pool.allocate_pages(need, shared=shared, reuse_len=reuse)
             self._inflight[slot] = _InFlight(
                 req=req, slot=slot,
@@ -1081,21 +1092,23 @@ class ServingEngine:
                    if not self._inflight[s].prefill_done]
         if not pending:
             return
-        B = self.num_slots
-        tokens = np.zeros((B, C), np.int32)
-        n_valid = np.ones((B,), np.int32)   # pads select position 0's logits
-        fresh = np.zeros((B,), bool)
-        is_real = np.zeros((B,), bool)
-        for fl in pending:
-            s = fl.slot
-            prompt = np.asarray(fl.req.prompt, np.int32)
-            n = min(C, len(prompt) - fl.prefilled)
-            tokens[s, :n] = prompt[fl.prefilled:fl.prefilled + n]
-            n_valid[s], fresh[s], is_real[s] = n, fl.fresh, True
-        tok, bad, self.pool.cache = self._prefill_multi_fn(
-            self.params, jnp.asarray(tokens), self.pool.cache,
-            jnp.asarray(n_valid), jnp.asarray(fresh), jnp.asarray(is_real),
-        )
+        with TraceAnnotation("engine.prefill.prepare"):
+            B = self.num_slots
+            tokens = np.zeros((B, C), np.int32)
+            n_valid = np.ones((B,), np.int32)   # pads select position 0
+            fresh = np.zeros((B,), bool)
+            is_real = np.zeros((B,), bool)
+            for fl in pending:
+                s = fl.slot
+                prompt = np.asarray(fl.req.prompt, np.int32)
+                n = min(C, len(prompt) - fl.prefilled)
+                tokens[s, :n] = prompt[fl.prefilled:fl.prefilled + n]
+                n_valid[s], fresh[s], is_real[s] = n, fl.fresh, True
+            args = (jnp.asarray(tokens), jnp.asarray(n_valid),
+                    jnp.asarray(fresh), jnp.asarray(is_real))
+        with TraceAnnotation("engine.prefill.dispatch"):
+            tok, bad, self.pool.cache = self._prefill_multi_fn(
+                self.params, args[0], self.pool.cache, *args[1:])
         self.stats["prefill_chunks"] += len(pending)
         self.stats["prefill_dispatches"] += 1
         finishers = []
@@ -1109,14 +1122,26 @@ class ServingEngine:
             if fl.prefill_done:
                 finishers.append(fl)
         if finishers:
-            tok_np = np.asarray(tok)      # materialize once for all rows
-            bad_np = np.asarray(bad)
+            # materialize once for all rows
+            tok_np, bad_np = self._fetch("engine.prefill.sync", tok, bad)
             self.stats["host_syncs"] += 1
-            for fl in finishers:
-                if bool(bad_np[fl.slot]) or fl.req.rid in self._inject_bad:
-                    self._quarantine(fl)
-                else:
-                    self._finish_prefill(fl, int(tok_np[fl.slot]))
+            with TraceAnnotation("engine.prefill.emit"):
+                for fl in finishers:
+                    if (bool(bad_np[fl.slot])
+                            or fl.req.rid in self._inject_bad):
+                        self._quarantine(fl)
+                    else:
+                        self._finish_prefill(fl, int(tok_np[fl.slot]))
+
+    def _fetch(self, span: str, *arrays) -> list:
+        """The device ``arrays`` on the host, read inside profiler span
+        ``span``: the step's wait on the device, which
+        ``stats["step_host_s"]`` leaves out."""
+        t = time.perf_counter()
+        with TraceAnnotation(span):
+            out = [np.asarray(a) for a in arrays]
+        self._waited += time.perf_counter() - t
+        return out
 
     def _decode_phase(self) -> None:
         active = [fl for fl in self._inflight.values()
@@ -1187,72 +1212,91 @@ class ServingEngine:
                   if fl.prefill_done and not fl.done]
         if not active:
             return 1
-        k = self._choose_horizon(active)
-        tokens = np.zeros((self.num_slots, 1), np.int32)
-        remaining = np.zeros((self.num_slots,), np.int32)
-        for fl in active:
-            tokens[fl.slot, 0] = fl.cur_token
-            # cap at k: the scan must not generate past this horizon even if
-            # bookkeeping and the device view of the budget ever diverged
-            remaining[fl.slot] = min(fl.remaining, k)
-        toks, bad, self.pool.cache = self._decode_horizon_fn(
-            self.params, jnp.asarray(tokens), self.pool.cache,
-            jnp.asarray(remaining), k=k,
-        )
-        toks_np = np.asarray(toks)        # the horizon's single host sync
-        bad_np = np.asarray(bad)
+        with TraceAnnotation("engine.decode.prepare"):
+            k = self._choose_horizon(active)
+            tokens = np.zeros((self.num_slots, 1), np.int32)
+            remaining = np.zeros((self.num_slots,), np.int32)
+            for fl in active:
+                tokens[fl.slot, 0] = fl.cur_token
+                # cap at k: the scan must not generate past this horizon
+                # even if bookkeeping and the device view of the budget ever
+                # diverged
+                remaining[fl.slot] = min(fl.remaining, k)
+            tokens, remaining = jnp.asarray(tokens), jnp.asarray(remaining)
+        with TraceAnnotation("engine.decode.dispatch", k=k, rows=len(active)):
+            toks, bad, self.pool.cache = self._decode_horizon_fn(
+                self.params, tokens, self.pool.cache, remaining, k=k)
+        # the horizon's single host sync
+        toks_np, bad_np = self._fetch("engine.decode.sync", toks, bad)
         self.stats["decode_steps"] += k
         self.stats["decode_dispatches"] += 1
         self.stats["host_syncs"] += 1
-        for fl in active:
-            if bool(bad_np[fl.slot]) or fl.req.rid in self._inject_bad:
-                # the bad flag is OR-ed across the horizon: the whole
-                # horizon's tokens for this row are untrusted and dropped
-                # (other rows are untouched — row independence)
-                self._quarantine(fl, at=self.clock + k - 1)
-                continue
-            new = [int(t) for t in toks_np[fl.slot, :k]]
-            fl.generated.extend(new)
-            fl.cur_token = new[-1]
-            self.stats["generated_tokens"] += k
-            self._emit_tokens(fl, new, self.clock)
-            if fl.done:
-                # the last token landed on the horizon's final tick — stamp
-                # completion with that tick, matching the stepwise timeline
-                self._retire(fl, at=self.clock + k - 1)
+        with TraceAnnotation("engine.decode.emit"):
+            for fl in active:
+                if bool(bad_np[fl.slot]) or fl.req.rid in self._inject_bad:
+                    # the bad flag is OR-ed across the horizon: the whole
+                    # horizon's tokens for this row are untrusted and
+                    # dropped (other rows are untouched — row independence)
+                    self._quarantine(fl, at=self.clock + k - 1)
+                    continue
+                new = [int(t) for t in toks_np[fl.slot, :k]]
+                fl.generated.extend(new)
+                fl.cur_token = new[-1]
+                self.stats["generated_tokens"] += k
+                self._emit_tokens(fl, new, self.clock)
+                if fl.done:
+                    # the last token landed on the horizon's final tick —
+                    # stamp completion with that tick, matching the
+                    # stepwise timeline
+                    self._retire(fl, at=self.clock + k - 1)
         return k
 
     def step(self) -> None:
         """One engine iteration: reap (deadlines/cancellations) → admit →
         chunked prefill → batched decode. On the fast path a fused decode
         horizon advances the engine clock by K ticks (one tick per
-        generated-token step, matching the stepwise path's timeline)."""
-        t0 = time.monotonic()
-        self._reap()
-        self._admit()
-        occ_pre = len(self._inflight) / self.num_slots
-        if self.fast:
-            self._prefill_phase_fast()
-            # a gen-at-prefill request may have retired above; ticks 2..K of
-            # the horizon see that state (no admissions can land mid-horizon
-            # — the arrival cap ends the horizon at the next arrival — and
-            # decode retires only on the final tick), so the occupancy
-            # accounting stays tick-identical to the stepwise path
-            occ_post = len(self._inflight) / self.num_slots
-            ticks = self._decode_phase_fast()
-            self.stats["occupancy_sum"] += occ_pre + occ_post * (ticks - 1)
-        else:
-            self._prefill_phase()
-            self._decode_phase()
-            ticks = 1
-            self.stats["occupancy_sum"] += occ_pre
-        self.stats["engine_steps"] += ticks
-        self.clock += float(ticks)
-        if self.straggler.observe(self.stats["engine_steps"],
-                                  time.monotonic() - t0):
-            self.stats["straggler_steps"] += 1
-        if self._pool_check:
-            self.check_invariants()
+        generated-token step, matching the stepwise path's timeline).
+
+        Each phase runs inside a profiler span (``engine.step`` over the
+        whole step; ``engine.reap``, ``engine.admit`` and, on the fast
+        path, ``engine.{prefill,decode}.{prepare,dispatch,sync,emit}``
+        inside it), recorded only while a JAX profiler session runs. The
+        stepwise reference path has only the step, reap and admit spans,
+        and its waits on the device count in ``stats["step_host_s"]``."""
+        with TraceAnnotation("engine.step"):
+            t0 = time.perf_counter()
+            self._waited = 0.0
+            with TraceAnnotation("engine.reap"):
+                self._reap()
+            with TraceAnnotation("engine.admit"):
+                self._admit()
+            occ_pre = len(self._inflight) / self.num_slots
+            if self.fast:
+                self._prefill_phase_fast()
+                # a gen-at-prefill request may have retired above; ticks
+                # 2..K of the horizon see that state (no admissions can land
+                # mid-horizon — the arrival cap ends the horizon at the next
+                # arrival — and decode retires only on the final tick), so
+                # the occupancy accounting stays tick-identical to the
+                # stepwise path
+                occ_post = len(self._inflight) / self.num_slots
+                ticks = self._decode_phase_fast()
+                self.stats["occupancy_sum"] += (occ_pre
+                                                + occ_post * (ticks - 1))
+            else:
+                self._prefill_phase()
+                self._decode_phase()
+                ticks = 1
+                self.stats["occupancy_sum"] += occ_pre
+            self.stats["engine_steps"] += ticks
+            self.clock += float(ticks)
+            took = time.perf_counter() - t0
+            self.stats["step_host_s"] += took - self._waited
+            self.stats["step_calls"] += 1
+            if self.straggler.observe(self.stats["engine_steps"], took):
+                self.stats["straggler_steps"] += 1
+            if self._pool_check:
+                self.check_invariants()
 
     def run(self, requests: Optional[Sequence[Request]] = None
             ) -> dict[int, RequestResult]:

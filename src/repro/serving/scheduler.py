@@ -43,6 +43,13 @@ class Request:
         preemption victims: when the pool can't cover the FIFO head, a
         strictly-lower-priority in-flight request may be preempted (pages
         released, request parked host-side) to make room.
+
+    The engine fills in two host-clock stamps (``time.perf_counter()``
+    seconds, the clock a client stamps its tokens with): ``t_submit`` when
+    ``submit`` queues the request, ``t_admit`` when admission first gives
+    it a slot (a preempted request resumes under its first stamp). Their
+    difference is the request's queue wait. Neither is an argument, and
+    ``dataclasses.replace`` starts a copy without them.
     """
 
     rid: int
@@ -51,6 +58,10 @@ class Request:
     arrival: float = 0.0
     deadline: Optional[float] = None
     priority: int = 0
+    t_submit: Optional[float] = dataclasses.field(
+        default=None, init=False, compare=False, repr=False)
+    t_admit: Optional[float] = dataclasses.field(
+        default=None, init=False, compare=False, repr=False)
 
     def __post_init__(self):
         if len(self.prompt) < 1:

@@ -195,3 +195,64 @@ def test_engine_decode_compiles_for_v5e(mesh_shape, topo, w8a8_engine,
     finally:
         set_serve_mesh(prev["mesh"], dp=prev["dp"], model=prev["model"])
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------- the names the chip benchmark reads by
+
+@pytest.fixture(scope="module")
+def w8a16_engine():
+    """``w8a8_engine``'s twin on the serve-w8a16 recipe."""
+    import dataclasses
+
+    import repro
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving import ServingEngine
+
+    cfg = dataclasses.replace(get_config("qwen2-0.5b"), n_layers=1)
+    qm = repro.quantize(build_model(cfg), recipe="serve-w8a16")
+    return ServingEngine(qm.model, qm.params, qm.cfg, num_slots=B,
+                         max_len=288, prefill_chunk=32)
+
+
+# the Pallas kernels each served program calls, by recipe
+PROGRAM_KERNELS = {
+    ("w8a8-kv8", "decode_horizon"): {"fused_decode", "qmatmul_w8a8",
+                                     "quantize_act"},
+    ("w8a8-kv8", "prefill_multi"): {"qmatmul_w8a8", "quantize_act"},
+    ("w8a16", "decode_horizon"): {"qmatmul_w8a16"},
+    ("w8a16", "prefill_multi"): {"qmatmul_w8a16"},
+}
+
+
+@pytest.mark.parametrize("recipe,jit", sorted(PROGRAM_KERNELS))
+def test_served_programs_keep_the_names_the_chip_benchmark_reads(
+        recipe, jit, one_chip, w8a8_engine, w8a16_engine, monkeypatch):
+    """The chip benchmark's trace reduction (``benchmarks/chip/chipbench/
+    kernels.py``) finds the decode and prefill programs by their module
+    names and each kernel by its op name, ``<kernel>_pallas.<n>``. Compiled
+    through the engine's own jits for a v5e, both still read as it expects."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
+                           / "benchmarks" / "chip"))
+    from chipbench import kernels, trace
+
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    eng = {"w8a8-kv8": w8a8_engine, "w8a16": w8a16_engine}[recipe]
+    fn, _, args, kw = eng.serve_jit_specs()[jit]
+    text = fn.lower(*_abstract(args, one_chip), **kw).compile().as_text()
+    module = text.split(None, 2)[1].rstrip(",")
+    assert module == f"jit__{jit}_impl"
+    assert kernels.is_decode(module) == (jit == "decode_horizon")
+    assert kernels.is_prefill(module) == (jit == "prefill_multi")
+    calls = [trace.Event(line.strip().removeprefix("ROOT "), 0, 0)
+             for line in text.splitlines() if "tpu_custom_call" in line
+             and " = " in line]
+    assert calls
+    found = {k for k in kernels.RATE
+             if any(kernels.matches(e, k) for e in calls)}
+    assert found == PROGRAM_KERNELS[(recipe, jit)]
+    assert all(trace.op_name(e).split(".")[0].endswith("_pallas")
+               for e in calls)
