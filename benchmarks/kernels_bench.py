@@ -88,7 +88,7 @@ def kernel_rows(smoke: bool = False):
 
     # --- int8-KV decode attention (one 32k-context token, 8 kv heads) ------
     B, S, H, hd = (2, 2048, 4, 64) if smoke else (8, 32768, 8, 128)
-    kq = jax.random.randint(ks[0], (B, S, H, hd), -127, 128, dtype=jnp.int8)
+    kq = jax.random.randint(ks[0], (B, S, H * hd), -127, 128, dtype=jnp.int8)
     ksc = jax.random.uniform(ks[1], (B, S, H), minval=0.01, maxval=0.05)
     qv = jax.random.normal(ks[0], (B, H, hd))
     f = jax.jit(lambda q, kq, ksc: kv_attention_ref(q, kq, ksc, kq, ksc))
@@ -120,6 +120,7 @@ def kernel_rows(smoke: bool = False):
     # on CPU); wall time regresses the XLA composition the CPU path serves
     from repro.kernels.fused_decode.ops import fused_decode
     from repro.kernels.kv_attention.ops import kv_attention_decode, quantize_kv
+    from repro.kernels.kv_attention.ref import flat_heads
     from repro.kernels.quantize_act.ops import quantize_act
 
     B, S, Hq, Hkv, hd = ((2, 512, 4, 2, 64) if smoke
@@ -128,6 +129,7 @@ def kernel_rows(smoke: bool = False):
     qv = jax.random.normal(kk[0], (B, Hq, hd))
     kq, ksc = quantize_kv(jax.random.normal(kk[1], (B, S, Hkv, hd)))
     vq, vsc = quantize_kv(jax.random.normal(kk[2], (B, S, Hkv, hd)))
+    kq, vq = flat_heads(kq), flat_heads(vq)              # lane-dense pool
     k_new = jax.random.normal(kk[3], (B, 1, Hkv, hd))
     v_new = jax.random.normal(kk[0], (B, 1, Hkv, hd))
     idx = jnp.full((B, 1), S // 2, jnp.int32)

@@ -113,6 +113,7 @@ def kernel_parity(seed: int) -> None:
 
     from repro.kernels.fused_decode.ops import fused_decode
     from repro.kernels.kv_attention.ops import kv_attention, quantize_kv
+    from repro.kernels.kv_attention.ref import flat_heads
     from repro.kernels.qmatmul_w8a8.ops import qmatmul_w8a8
     from repro.kernels.qmatmul_w8a16.ops import qmatmul_w8a16
     from repro.kernels.quantize_act.ops import quantize_act
@@ -169,6 +170,8 @@ def kernel_parity(seed: int) -> None:
     q = jax.random.normal(ks[0], (SLOTS, HQ, HD), jnp.bfloat16)
     ck, cks = quantize_kv(jax.random.normal(ks[1], (SLOTS, RING, HKV, HD)))
     cv, cvs = quantize_kv(jax.random.normal(ks[2], (SLOTS, RING, HKV, HD)))
+    # the lane-dense [B, S, Hkv·hd] payload the cache pool holds
+    ck, cv = flat_heads(ck), flat_heads(cv)
     live = (jnp.arange(RING)[None, :] < lengths[:, None])[..., None]
     cks, cvs = jnp.where(live, cks, 0.0), jnp.where(live, cvs, 0.0)
     # exp and fp32 sums in another order on the VPU: a few ulps

@@ -70,7 +70,8 @@ class JitArtifact:
     cache_leaves_local: list = dataclasses.field(default_factory=list)
     # "full dequant" element threshold: one slot's ring of one layer's KV
     slot_cache_elems: int = 1 << 62
-    # trailing dims of a cache payload leaf ([S, Hkv, hd]) — a materialized
+    # trailing dims of a cache payload leaf ([S, Hkv·hd] for the lane-dense
+    # int8 pool, [S, Hkv, hd] for fp) — a materialized
     # s8 convert matching these is a whole-ring dequant (dtype-ledger)
     cache_payload_dims: tuple = ()
     # (hlo_dtype, dims) of the paged pool's page-table leaf (global + local;
@@ -148,13 +149,14 @@ def graph_from_engine(engine, recipe: str = "",
                          else dims)]
     k_shape = pool.cache["k"].shape
     if pool.paged:
-        # paged leaves are [L, NP, pg, Hkv, hd], but the jits attend through
-        # the gathered DENSE view [L, B, S, Hkv, hd] — the dtype ledger's
+        # paged leaves are [L, NP, pg, ...], but the jits attend through
+        # the gathered DENSE view [L, B, S, ...] — the dtype ledger's
         # "whole-ring dequant" threshold and payload-dims matcher must see
         # the view dims or a paged prefill dequant would sail under them
-        payload_dims = (engine.max_len, int(k_shape[3]), int(k_shape[4]))
+        payload_dims = (engine.max_len,) + tuple(int(d) for d in k_shape[3:])
     else:
-        payload_dims = tuple(int(d) for d in k_shape[2:])  # [S, Hkv, hd]
+        # [S, Hkv, hd] (fp) or [S, Hkv·hd] (lane-dense int8)
+        payload_dims = tuple(int(d) for d in k_shape[2:])
     slot_elems = int(np.prod(payload_dims))      # one slot, one layer
     if mesh_shape is None and engine.mesh is not None:
         mesh_shape = tuple(

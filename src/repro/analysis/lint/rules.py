@@ -298,7 +298,7 @@ def _debt_covers(entries: list[dict], key: str, value) -> bool:
 # ============================================================== core rules
 def is_cache_dequant(record: ConvertRecord, artifact) -> bool:
     """A materialized s8→float convert whose trailing dims are a whole
-    cache-ring footprint ([..., S, Hkv, hd]) — the "full [B,S,H,hd]
+    cache-ring footprint ([..., S, Hkv·hd] int8) — the "full [B,S,H,hd]
     dequant" the paper-level invariant forbids. Weight dequants ([K,N],
     the w8a16 XLA-fallback scale-fold) never match: they are pinned by the
     ledger totals instead of erroring per instance."""

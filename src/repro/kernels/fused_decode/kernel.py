@@ -12,8 +12,9 @@ touches HBM at all.
 
 Semantics are the ``kv_attention`` kernel's, inherited verbatim (zero-scale
 masking, GQA via padded repeat-kv head groups, grid (B, S/blk) with
-per-batch online-softmax scratch, the same lane-dense ``[B, Hkv, 1, S]``
-scale layout) — the attention step IS ``kv_attention.kernel.attend_block``,
+per-batch online-softmax scratch, the same lane-dense ``[B, S, Hkv·hd]``
+payload and ``[B, Hkv, 1, S]`` scale layouts) — the attention step IS
+``kv_attention.kernel.attend_block`` over each kv head's lanes,
 so the fused path stays bit-exact to the stepwise composition, which is
 what the serving parity batteries pin. The ``valid`` mask is the caller's
 post-append liveness mask (it must cover the new token's position — the
@@ -37,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..kv_attention.kernel import (
     attend_block,
     finish,
+    head_lanes,
     head_major,
     init_state,
     lane_scales,
@@ -44,26 +46,37 @@ from ..kv_attention.kernel import (
 )
 
 
-def _quant127(t):
-    """The ``ops.quantize_kv`` formula, in-kernel: [..., hd] fp → (int8,
-    fp32 absmax/127 scale [..., 1]). Must stay expression-identical to the
-    host-side quantizer — the scale floor keeps 0 reserved for "invalid"."""
-    tf = t.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(tf), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(tf / scale), -127, 127).astype(jnp.int8)
-    return q, scale
+def _quant127(row, hd):
+    """The ``ops.quantize_kv`` formula, in-kernel, per kv head of a
+    lane-dense row: [1, Hkv·hd] fp → (int8 [1, Hkv·hd], one fp32
+    absmax/127 scale [1, 1] per head). Each head's absmax is taken over its
+    own lanes (the others masked to 0, which no |x| exceeds) and every lane
+    divides by its head's scale, so the result is expression-identical to
+    the host-side quantizer — the scale floor keeps 0 reserved for
+    "invalid"."""
+    tf = row.astype(jnp.float32)
+    head = jax.lax.broadcasted_iota(jnp.int32, tf.shape, 1) // hd
+    lane_scale = jnp.zeros_like(tf)
+    scales = []
+    for h in range(tf.shape[1] // hd):
+        amax = jnp.max(jnp.where(head == h, jnp.abs(tf), 0.0), axis=-1,
+                       keepdims=True)
+        scale = jnp.maximum(amax, 1e-8) / 127.0
+        lane_scale = jnp.where(head == h, scale, lane_scale)
+        scales.append(scale)
+    q = jnp.clip(jnp.round(tf / lane_scale), -127, 127).astype(jnp.int8)
+    return q, scales
 
 
-def _append(new_ref, q_ref, s_ref, oq_ref, os_ref, hit_blk, hit_row):
+def _append(new_ref, q_ref, s_ref, oq_ref, os_ref, hit_blk, hit_row, hd):
     """Quantize the new token's K or V and write it into its ring row of
     this cache block (a no-op select when the row is elsewhere): the int8
-    payload as one [blk, Hkv, hd] block, the scales per kv head as
-    [1, blk] rows."""
-    q_n, s_n = _quant127(new_ref[0])                  # [1, Hkv, hd], [1, Hkv, 1]
+    payload as one lane-dense [blk, Hkv·hd] block, the scales per kv head
+    as [1, blk] rows."""
+    q_n, s_n = _quant127(new_ref[0], hd)               # [1, Hkv·hd], Hkv×[1, 1]
     oq_ref[0] = jnp.where(hit_blk, q_n, q_ref[0])
-    for h in range(s_ref.shape[1]):
-        os_ref[0, h] = jnp.where(hit_row, s_n[:, h, :], s_ref[0, h])
+    for h, s_h in enumerate(s_n):
+        os_ref[0, h] = jnp.where(hit_row, s_h, s_ref[0, h])
 
 
 def _kernel(idx_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, kn_ref, vn_ref,
@@ -82,19 +95,20 @@ def _kernel(idx_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, kn_ref, vn_ref,
 
     # ---- append-quantize: the new token lands in this block iff its ring
     # offset falls inside [j·blk, (j+1)·blk)
+    hd = q_ref.shape[-1]
     off = idx_ref[b] - j * blk
-    hit_blk = jax.lax.broadcasted_iota(jnp.int32, (blk, 1, 1), 0) == off
+    hit_blk = jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0) == off
     hit_row = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1) == off
-    _append(kn_ref, kq_ref, ks_ref, okq_ref, oks_ref, hit_blk, hit_row)
-    _append(vn_ref, vq_ref, vs_ref, ovq_ref, ovs_ref, hit_blk, hit_row)
+    _append(kn_ref, kq_ref, ks_ref, okq_ref, oks_ref, hit_blk, hit_row, hd)
+    _append(vn_ref, vq_ref, vs_ref, ovq_ref, ovs_ref, hit_blk, hit_row, hd)
     # the stored scales are UNMASKED (the cache keeps every written token);
     # only the attention inputs see the caller's liveness mask
     live = valid_ref[0] > 0                                  # [1, blk]
     for h in range(q_ref.shape[1]):
         # ---- attention over the updated block: kv_attention's own step
         m, l, acc = attend_block(
-            q_ref[0, h].astype(jnp.float32), okq_ref[0, :, h, :],
-            jnp.where(live, oks_ref[0, h], 0.0), ovq_ref[0, :, h, :],
+            q_ref[0, h], head_lanes(okq_ref, h, hd),
+            jnp.where(live, oks_ref[0, h], 0.0), head_lanes(ovq_ref, h, hd),
             jnp.where(live, ovs_ref[0, h], 0.0),
             m_ref[h], l_ref[h], acc_ref[h], scale=scale)
         m_ref[h], l_ref[h], acc_ref[h] = m, l, acc
@@ -128,9 +142,10 @@ def _kernel(idx_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, kn_ref, vn_ref,
 def fused_decode_pallas(q, k_q, k_s, v_q, v_s, k_new, v_new, idx, valid, *,
                         blk=512, out_dtype=jnp.float32, quantize_out=False,
                         interpret=False):
-    """q [B, Hq, hd]; k_q/v_q [B, S, Hkv, hd] int8; k_s/v_s [B, S, Hkv];
-    k_new/v_new [B, Hkv, hd] fp; idx [B] int32 ring offsets; valid [B, S]
-    fp mask (>0 = live, must include each row's new position).
+    """q [B, Hq, hd]; k_q/v_q [B, S, Hkv·hd] int8 (lane-dense: kv head h
+    owns lanes [h·hd, (h+1)·hd)); k_s/v_s [B, S, Hkv]; k_new/v_new
+    [B, Hkv, hd] fp; idx [B] int32 ring offsets; valid [B, S] fp mask
+    (>0 = live, must include each row's new position).
 
     Returns (out, k_q', k_s', v_q', v_s') — the payload outputs aliased onto
     their inputs — plus (out_q [B, Hq·hd] int8, out_scale [B]) when
@@ -138,7 +153,9 @@ def fused_decode_pallas(q, k_q, k_s, v_q, v_s, k_new, v_new, idx, valid, *,
     pads with zero-scale masked positions); on TPU ``blk`` is a multiple of
     128 or all of S.
     """
-    B, S, Hkv, hd = k_q.shape
+    B, S, width = k_q.shape
+    Hkv = k_s.shape[-1]
+    hd = width // Hkv
     Hq = q.shape[1]
     assert S % blk == 0
     assert Hq % Hkv == 0
@@ -148,10 +165,9 @@ def fused_decode_pallas(q, k_q, k_s, v_q, v_s, k_new, v_new, idx, valid, *,
     qh = head_major(q, Hkv)
     gp = qh.shape[2]
     head_spec = pl.BlockSpec((1, Hkv, gp, hd), lambda b, j, ix: (b, 0, 0, 0))
-    cache_spec = pl.BlockSpec((1, blk, Hkv, hd),
-                              lambda b, j, ix: (b, j, 0, 0))
+    cache_spec = pl.BlockSpec((1, blk, width), lambda b, j, ix: (b, j, 0))
     scale_spec = pl.BlockSpec((1, Hkv, 1, blk), lambda b, j, ix: (b, 0, 0, j))
-    new_spec = pl.BlockSpec((1, 1, Hkv, hd), lambda b, j, ix: (b, 0, 0, 0))
+    new_spec = pl.BlockSpec((1, 1, width), lambda b, j, ix: (b, 0, 0))
     out_shape = [
         jax.ShapeDtypeStruct((B, Hkv, gp, hd), out_dtype),
         jax.ShapeDtypeStruct(k_q.shape, jnp.int8),
@@ -185,7 +201,7 @@ def fused_decode_pallas(q, k_q, k_s, v_q, v_s, k_new, v_new, idx, valid, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(idx.astype(jnp.int32), qh, k_q, lane_scales(k_s), v_q, lane_scales(v_s),
-      k_new.reshape(B, 1, Hkv, hd), v_new.reshape(B, 1, Hkv, hd),
+      k_new.reshape(B, 1, width), v_new.reshape(B, 1, width),
       valid.astype(jnp.float32).reshape(B, 1, S))
     out = res[0][:, :, :group].reshape(B, Hq, hd)
 

@@ -59,7 +59,9 @@ def _pallas_impl(q, ck, cks, cv, cvs, k_new, v_new, idx, *, valid, out_dtype,
                  blk, quantize_out, interpret):
     from ..kv_attention.ref import pad_to_block
 
-    B, S, Hkv, hd = ck.shape
+    B, S = ck.shape[:2]
+    Hkv = cks.shape[-1]
+    hd = q.shape[-1]
     # normalize the stepwise op's idx/valid conventions to kernel shapes
     idx_b = idx[:, 0] if idx.ndim == 2 else jnp.broadcast_to(
         idx.reshape(-1)[:1], (B,))
@@ -117,12 +119,14 @@ def _fd_ref(q, ck, cks, cv, cvs, k_new, v_new, idx, *, valid, out_dtype,
 
 def fused_decode(q, cache_k, cache_ks, cache_v, cache_vs, k_new, v_new, idx,
                  *, valid=None, out_dtype=jnp.float32,
-                 backend: Optional[str] = None, blk: int = 512,
+                 backend: Optional[str] = None,
+                 blk: Optional[int] = None,
                  cache_verr=None, quantize_out: bool = False):
     """Fused decode step: append-quantize the new token, attend, and
     (optionally) re-quantize the output row for the W8A8 wo projection.
 
-    q [B, Hq, hd]; cache leaves as in ``kv_attention_decode``; k_new/v_new
+    q [B, Hq, hd]; cache leaves as in ``kv_attention_decode`` (lane-dense
+    int8 payload [B, S, Hkv·hd], scales [B, S, Hkv]); k_new/v_new
     [B, 1, Hkv, hd]; idx [B, 1] per-slot ring offsets (or [1] shared);
     ``valid`` [B|1, S] marks live cache positions (must include the new
     token's). Returns ``(out, updated_leaves)``, where ``out`` becomes the
@@ -151,9 +155,9 @@ def _spec(*, head_dim: int = 16, n_kv_heads: int = 2, n_q_heads: int = 4,
     B, S, Hq, Hkv, hd = batch, seq, n_q_heads, n_kv_heads, head_dim
     return (fused_decode,
             (jnp.zeros((B, Hq, hd), jnp.float32),        # q
-             jnp.zeros((B, S, Hkv, hd), jnp.int8),       # cache_k
+             jnp.zeros((B, S, Hkv * hd), jnp.int8),      # cache_k
              jnp.ones((B, S, Hkv), jnp.float32),         # cache_ks
-             jnp.zeros((B, S, Hkv, hd), jnp.int8),       # cache_v
+             jnp.zeros((B, S, Hkv * hd), jnp.int8),      # cache_v
              jnp.ones((B, S, Hkv), jnp.float32),         # cache_vs
              jnp.zeros((B, 1, Hkv, hd), jnp.float32),    # k_new
              jnp.zeros((B, 1, Hkv, hd), jnp.float32),    # v_new

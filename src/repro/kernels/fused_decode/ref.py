@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 
 def fused_decode_ref(q, cache_k, cache_ks, cache_v, cache_vs, k_new, v_new,
-                    idx, *, valid=None, out_dtype=jnp.float32, blk=512,
+                    idx, *, valid=None, out_dtype=jnp.float32, blk=None,
                     quantize_out=False):
     from ..kv_attention.ops import kv_attention_decode
     from ..quantize_act.ref import quantize_act_ref

@@ -14,6 +14,10 @@ decode memory wall:
     new token's K/V is quantized once, scattered into the int8 cache, and
     attention runs over the updated cache — the cache itself is never
     re-quantized or re-materialized in fp.
+
+The cache payload the ops read and write is lane-dense, ``[B, S, Hkv·hd]``
+int8 (kv head ``h`` owns lanes ``[h·hd, (h+1)·hd)``), with scales
+``[B, S, Hkv]``; the new token's K/V arrives per head, ``[B, T, Hkv, hd]``.
 """
 from __future__ import annotations
 
@@ -23,7 +27,7 @@ import jax.numpy as jnp
 
 from ..dispatch import register_impl, register_spec, resolve
 from .kernel import kv_attention_pallas
-from .ref import kv_attention_ref, kv_attention_xla, pad_to_block
+from .ref import flat_heads, kv_attention_ref, kv_attention_xla, pad_to_block
 
 
 def quantize_kv(t: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -71,19 +75,20 @@ def _kv_ref(q, k_q, k_s, v_q, v_s, *, blk, out_dtype):
     return kv_attention_ref(q, k_q, k_s, v_q, v_s, out_dtype, blk=blk)
 
 
-def kv_attention(q, k_q, k_s, v_q, v_s, *, blk: int = 512,
+def kv_attention(q, k_q, k_s, v_q, v_s, *, blk: Optional[int] = None,
                  out_dtype=jnp.float32, backend: Optional[str] = None,
                  v_err: Optional[jnp.ndarray] = None):
     """Single-token decode attention over an int8 cache.
 
-    q [B, Hq, hd]; k_q/v_q [B, S, Hkv, hd] int8; k_s/v_s [B, S, Hkv] with
+    q [B, Hq, hd]; k_q/v_q [B, S, Hkv·hd] int8; k_s/v_s [B, S, Hkv] with
     Hq a multiple of Hkv (GQA, repeat-kv head order). Positions with scale 0
     are masked (ragged per-slot lengths / ring holes / padding) — zero the
     scales of invalid positions instead of dequantizing-and-masking.
     ``v_err`` ([B, S, Hkv] per-token V dequant-error means) enables the
     optional bias correction — XLA path only: with ``backend=None`` it
     selects "xla", an explicit "pallas"/"interpret" raises (no silent
-    hot-path fallback).
+    hot-path fallback). ``blk`` is the kernel's block of cache positions;
+    None sizes it by bytes (``ref.block_rows``).
     """
     if v_err is not None:
         if backend not in (None, "xla"):
@@ -101,9 +106,10 @@ def append_quantize(cache_k, cache_ks, cache_v, cache_vs, k_new, v_new, idx,
                     *, cache_verr=None):
     """Quantize a new token's K/V once and scatter it into the int8 cache.
 
-    k_new/v_new [B, T, Hkv, hd] fp; idx [T] ring offsets (scalar-pos cache)
-    or [B, T] per-slot offsets. Returns the updated cache leaves (+ the
-    per-token V dequant-error means when ``cache_verr`` is given).
+    k_new/v_new [B, T, Hkv, hd] fp, written as lane-dense [B, T, Hkv·hd]
+    rows; idx [T] ring offsets (scalar-pos cache) or [B, T] per-slot
+    offsets. Returns the updated cache leaves (+ the per-token V
+    dequant-error means when ``cache_verr`` is given).
     """
     k_q, k_s = quantize_kv(k_new)
     v_q, v_s = quantize_kv(v_new)
@@ -112,8 +118,8 @@ def append_quantize(cache_k, cache_ks, cache_v, cache_vs, k_new, v_new, idx,
         at = lambda c, u: c.at[row, idx].set(u)
     else:                                              # shared ring offsets
         at = lambda c, u: c.at[:, idx].set(u)
-    out = (at(cache_k, k_q), at(cache_ks, k_s),
-           at(cache_v, v_q), at(cache_vs, v_s))
+    out = (at(cache_k, flat_heads(k_q)), at(cache_ks, k_s),
+           at(cache_v, flat_heads(v_q)), at(cache_vs, v_s))
     if cache_verr is not None:
         err = jnp.mean(v_q.astype(jnp.float32) * v_s[..., None]
                        - v_new.astype(jnp.float32), axis=-1)
@@ -123,11 +129,13 @@ def append_quantize(cache_k, cache_ks, cache_v, cache_vs, k_new, v_new, idx,
 
 def kv_attention_decode(q, cache_k, cache_ks, cache_v, cache_vs, k_new, v_new,
                         idx, *, valid=None, out_dtype=jnp.float32,
-                        backend: Optional[str] = None, blk: int = 512,
+                        backend: Optional[str] = None,
+                        blk: Optional[int] = None,
                         cache_verr=None):
     """Fused decode step: append-quantize the new token, then attend.
 
-    q [B, Hq, hd] (the new token's roped query); k_new/v_new [B, 1, Hkv, hd];
+    q [B, Hq, hd] (the new token's roped query); cache_k/cache_v
+    [B, S, Hkv·hd] int8; k_new/v_new [B, 1, Hkv, hd];
     ``valid`` [B, S] marks live cache positions (None = all live). Returns
     (attn_out [B, Hq, hd], updated cache leaves) — the int8 cache is written
     once per token and never re-quantized.
@@ -154,9 +162,9 @@ def _spec(*, head_dim: int = 16, n_kv_heads: int = 2, n_q_heads: int = 4,
     B, S, Hq, Hkv, hd = batch, seq, n_q_heads, n_kv_heads, head_dim
     return (kv_attention_decode,
             (jnp.zeros((B, Hq, hd), jnp.float32),        # q
-             jnp.zeros((B, S, Hkv, hd), jnp.int8),       # cache_k
+             jnp.zeros((B, S, Hkv * hd), jnp.int8),      # cache_k
              jnp.ones((B, S, Hkv), jnp.float32),         # cache_ks
-             jnp.zeros((B, S, Hkv, hd), jnp.int8),       # cache_v
+             jnp.zeros((B, S, Hkv * hd), jnp.int8),      # cache_v
              jnp.ones((B, S, Hkv), jnp.float32),         # cache_vs
              jnp.zeros((B, 1, Hkv, hd), jnp.float32),    # k_new
              jnp.zeros((B, 1, Hkv, hd), jnp.float32),    # v_new

@@ -216,7 +216,9 @@ def _fused_decode_tp(part, q1, cache, k_new, v_new, idx, valid, out_dtype,
     """shard_map the decode attention ``op`` (``fused_decode``, or the
     stepwise ``kv_attention_decode`` with fusion off) over (data, model): q
     heads and the KV cache's head axis live on "model", slots on "data".
-    Attention is head-local, so the body emits no collectives — the -tp
+    The int8 payload's lane-dense last axis (Hkv·hd) splits into whole
+    heads, since the model axis divides Hkv here. Attention is head-local,
+    so the body emits no collectives — the -tp
     serving contracts pin the decode collective budget at the same level as
     single-device."""
     from jax.sharding import PartitionSpec as P
@@ -233,9 +235,9 @@ def _fused_decode_tp(part, q1, cache, k_new, v_new, idx, valid, out_dtype,
         mesh=mesh,
         in_specs=(
             P(dp, mdl, None),                    # q [B, Hq, hd]
-            P(dp, None, mdl, None),              # cache k [B, S, Hkv, hd]
+            P(dp, None, mdl),                    # cache k [B, S, Hkv·hd]
             P(dp, None, mdl),                    # k_scale [B, S, Hkv]
-            P(dp, None, mdl, None),              # cache v
+            P(dp, None, mdl),                    # cache v
             P(dp, None, mdl),                    # v_scale
             P(dp, None, mdl, None),              # k_new [B, 1, Hkv, hd]
             P(dp, None, mdl, None),              # v_new
@@ -243,8 +245,7 @@ def _fused_decode_tp(part, q1, cache, k_new, v_new, idx, valid, out_dtype,
             P(dp, None) if per_slot else P(None, None),  # valid [B|1, S]
         ),
         out_specs=(P(dp, mdl, None),
-                   (P(dp, None, mdl, None), P(dp, None, mdl),
-                    P(dp, None, mdl, None), P(dp, None, mdl))),
+                   (P(dp, None, mdl),) * 4),
         check_vma=False,
     )
     return fn(q1, cache["k"], cache["k_scale"], cache["v"], cache["v_scale"],
@@ -422,7 +423,9 @@ def attention_block(
     """Full attention sub-block: qkv proj → rope → (cached) attention → out.
 
     cache (decode): {"k": [B, S, n_kv, hd], "v": ..., "pos": int32 scalar}
-    written as a ring buffer of length S (S = min(seq, window) for SWA).
+    written as a ring buffer of length S (S = min(seq, window) for SWA); an
+    int8 cache holds lane-dense "k"/"v" [B, S, n_kv·hd] beside its
+    "k_scale"/"v_scale" [B, S, n_kv].
     Returns (out, new_cache, stats).
     """
     B, T, D = x.shape
@@ -465,7 +468,9 @@ def attention_block(
         # absolute token position (-1 = never written). With "k_scale" in the
         # cache the payload is INT8 (per-token, per-head absmax scales) —
         # DFQ's deployment story applied to the decode memory wall: the
-        # cache-stream roofline term halves vs bf16.
+        # cache-stream roofline term halves vs bf16. The int8 payload is
+        # lane-dense, [B, S, n_kv·hd]: the layout the decode kernel reads
+        # in place.
         S = cache["k"].shape[1]
         pos = cache["pos"]
         # pos may be a scalar (whole-batch serving: every row at the same
@@ -496,6 +501,7 @@ def attention_block(
                 append_quantize,
                 kv_attention_decode,
             )
+            from ..kernels.kv_attention.ref import split_heads
 
             valid = m[:, 0, :] if per_slot else m[0][None, :]     # [B|1, S]
             if T == 1:
@@ -563,8 +569,10 @@ def attention_block(
                     cache_verr=cache.get("v_err"),
                 )
                 ck, ks, cv, vs = leaves[:4]
-                k = ck.astype(x.dtype) * ks.astype(x.dtype)[..., None]
-                v = cv.astype(x.dtype) * vs.astype(x.dtype)[..., None]
+                k = (split_heads(ck.astype(x.dtype), nkv)
+                     * ks.astype(x.dtype)[..., None])
+                v = (split_heads(cv.astype(x.dtype), nkv)
+                     * vs.astype(x.dtype)[..., None])
                 if "v_err" in cache:
                     # Σ p (ṽ − e) == Σ p ṽ − Σ p e: same correction as decode
                     v = v - leaves[4].astype(x.dtype)[..., None]

@@ -357,8 +357,9 @@ class LMModel:
         batch row is an independent serving slot with its own write offset
         (``pos`` [B]) and absolute slot positions (``kpos`` [B, S]), so the
         engine can prefill/retire rows at different sequence positions.
-        ``kv_bits`` overrides ``cfg.kv_cache_bits`` (8 → int8 payload +
-        per-token/per-head scales; 16 → fp payload in ``dtype``)."""
+        ``kv_bits`` overrides ``cfg.kv_cache_bits`` (8 → lane-dense int8
+        payload [L, B, S, Hkv·hd] + per-token/per-head scales [L, B, S,
+        Hkv]; 16 → fp payload [L, B, S, Hkv, hd] in ``dtype``)."""
         cfg = self.cfg
         kv_bits = cfg.kv_cache_bits if kv_bits is None else int(kv_bits)
         if kv_bits not in (8, 16):
@@ -377,10 +378,16 @@ class LMModel:
                 "conv": jnp.zeros((cfg.n_layers, batch, cfg.ssm_conv_width - 1, d_conv), dtype),
                 "pos": jnp.zeros((), jnp.int32),
             }
+        # the int8 payload is lane-dense, [L, B, S, Hkv·hd]: one cache
+        # position of every kv head is one row, the layout the decode
+        # kernel reads in place; the fp payload keeps [L, B, S, Hkv, hd]
+        kv_shape = ((cfg.n_layers, batch, S, cfg.n_kv_heads * cfg.head_dim)
+                    if kv_bits == 8 else
+                    (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim))
         kv_dtype = jnp.int8 if kv_bits == 8 else dtype
         kv = {
-            "k": jnp.zeros((cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim), kv_dtype),
-            "v": jnp.zeros((cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim), kv_dtype),
+            "k": jnp.zeros(kv_shape, kv_dtype),
+            "v": jnp.zeros(kv_shape, kv_dtype),
             "kpos": (jnp.full((batch, S), -1, jnp.int32) if per_slot
                      else jnp.full((S,), -1, jnp.int32)),
             "pos": (jnp.zeros((batch,), jnp.int32) if per_slot

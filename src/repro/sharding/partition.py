@@ -216,18 +216,30 @@ def batch_pspec(mesh: Mesh, ndim: int = 2, batch: Optional[int] = None) -> P:
     return P(dp, *([None] * (ndim - 1)))
 
 
+def _payload_heads(paths: dict) -> dict:
+    """{path of an int8 cache payload "k"/"v": its kv head count}, read off
+    the "k_scale"/"v_scale" sibling ([..., H]). The lane-dense payload's
+    last axis is H·hd, and a "model" shard of it must hold whole heads, so
+    it shards only where H divides — not wherever H·hd does."""
+    return {p[:-len("_scale")]: leaf.shape[-1] for p, leaf in paths.items()
+            if p.endswith(("/k_scale", "/v_scale"))}
+
+
 def cache_pspecs(cache_shapes: Any, mesh: Mesh, batch: int) -> Any:
     """KV/SSM cache sharding: batch over (pod, data) when divisible, else
     sequence over "data" (the long-context B=1 case); heads over "model"."""
     dp_axes, dp_n = _dp_world(mesh)
     model_n = mesh.shape.get("model", 1)
+    paths = dict(_walk(cache_shapes))
+    heads = _payload_heads(paths)
 
     def spec_of(path, leaf):
         shape = leaf.shape
         if len(shape) <= 1:
             return P()
         axes: list = [None] * len(shape)
-        # layouts: k/v [L, B, S, H, hd]; ssm [L, B, H, P, S]; conv [L, B, W, C]
+        # layouts: k/v [L, B, S, H, hd] (int8: [L, B, S, H·hd]);
+        # ssm [L, B, H, P, S]; conv [L, B, W, C]
         if len(shape) >= 3:
             B_dim = 1
             if shape[B_dim] % dp_n == 0 and shape[B_dim] >= dp_n:
@@ -243,24 +255,25 @@ def cache_pspecs(cache_shapes: Any, mesh: Mesh, batch: int) -> Any:
                 # dequant-error means): follow the payload sharding
                 if shape[2] % model_n == 0 and shape[2] >= model_n:
                     axes[2] = "model"
-            if (path.endswith("/k") or path.endswith("/v")) and len(shape) == 5:
+            if (path.endswith("/k") or path.endswith("/v")) and len(shape) >= 4:
                 # Prefer SEQUENCE sharding of the cache over "model": the
                 # pv contraction then psums a tiny [B,H,1,hd] partial per
                 # layer. Sharding heads/head_dim instead psums [B,H,1,S]
                 # score rows — measured 22.6 GB/device/step on yi-34b
                 # decode_32k (EXPERIMENTS §Perf iteration C2).
+                H = heads.get(path, shape[3])
                 if axes[2] is None and shape[2] % model_n == 0 and shape[2] >= model_n:
                     axes[2] = "model"
-                elif shape[3] % model_n == 0 and shape[3] >= model_n:
+                elif H % model_n == 0 and H >= model_n:
                     axes[3] = "model"
-                elif shape[4] % model_n == 0 and shape[4] >= model_n:
+                elif (len(shape) == 5 and shape[4] % model_n == 0
+                      and shape[4] >= model_n):
                     axes[4] = "model"
             if path.endswith("/ssm") and len(shape) == 5:
                 if shape[2] % model_n == 0:
                     axes[2] = "model"
         return P(*axes)
 
-    paths = dict(_walk(cache_shapes))
     flat = {p: spec_of(p, l) for p, l in paths.items()}
     return _rebuild(cache_shapes, flat)
 
@@ -269,22 +282,25 @@ def serve_cache_pspecs(cache_shapes: Any, mesh: Mesh) -> Any:
     """Serving (per-slot pooled) cache sharding for the continuous-batching
     engine: the SLOT axis shards over "data" and KV heads over "model".
 
-    Layouts: k/v [L, B, S, H, hd]; k_scale/v_scale/v_err [L, B, S, H];
-    kpos [B, S]; pos [B] — B is the slot axis. Rules:
+    Layouts: k/v [L, B, S, H, hd] (fp) or lane-dense [L, B, S, H·hd]
+    (int8); k_scale/v_scale/v_err [L, B, S, H]; kpos [B, S]; pos [B] — B is
+    the slot axis. Rules:
 
       * slots over ("pod",) "data" when the pool size divides the DP world —
         no MIN_SHARD_DIM floor here: slot pools are inherently small and
         every slot's computation is row-independent, so slot sharding is
         exact (it never changes a reduction order),
       * KV heads over "model" when divisible (head-parallel attention — each
-        head's softmax·V stays device-local),
+        head's softmax·V stays device-local); the int8 payload's lane-dense
+        H·hd axis splits over "model" when H does, so each shard holds
+        contiguous whole heads,
       * the int8-cache scale leaves (k_scale/v_scale) and the V dequant-error
         means (v_err) FOLLOW their payload tensor: same slot axis, same head
         axis, so a shard dequantizes its own cache columns locally,
       * anything non-divisible replicates (graceful degradation).
 
     **Paged pools** (a ``page_table`` leaf is present; payload leaves are
-    [L, NP, pg, H(, hd)]) shard KV heads (axis 3) over "model" exactly like
+    [L, NP, pg, ...]) shard KV heads (axis 3) over "model" exactly like
     the contiguous layout, but the PAGE axis — and the page tables and
     dense kpos/pos bookkeeping — replicate. Sharding pages over "data"
     looks symmetric to slot-sharding, but the paged jits address pages
@@ -299,6 +315,7 @@ def serve_cache_pspecs(cache_shapes: Any, mesh: Mesh) -> Any:
     model_n = mesh.shape.get("model", 1)
     paths = dict(_walk(cache_shapes))
     paged = any(p.rsplit("/", 1)[-1] == "page_table" for p in paths)
+    heads = _payload_heads(paths)
 
     def spec_of(path, leaf):
         shape = leaf.shape
@@ -313,7 +330,8 @@ def serve_cache_pspecs(cache_shapes: Any, mesh: Mesh) -> Any:
             if (not paged and shape[1] % dp_n == 0 and shape[1] >= dp_n):
                 axes[1] = dp_axes                       # slot axis
             H_dim = 3                                   # heads (payload + scales)
-            if shape[H_dim] % model_n == 0 and shape[H_dim] >= model_n:
+            H = heads.get(path, shape[H_dim])
+            if H % model_n == 0 and H >= model_n:
                 axes[H_dim] = "model"
             return P(*axes)
         return P(*axes)

@@ -12,6 +12,7 @@ import pytest
 
 from repro.kernels.fused_decode.ops import fused_decode, fusion_enabled
 from repro.kernels.kv_attention.ops import kv_attention_decode, quantize_kv
+from repro.kernels.kv_attention.ref import flat_heads
 from repro.kernels.quantize_act.ops import quantize_act
 
 
@@ -24,6 +25,8 @@ def _decode_inputs(B=2, S=64, Hq=4, Hkv=2, hd=16, seed=3):
     v = jax.random.normal(ks[2], (B, S, Hkv, hd))
     k_q, k_s = quantize_kv(k)
     v_q, v_s = quantize_kv(v)
+    # the lane-dense [B, S, Hkv·hd] payload the cache pool holds
+    k_q, v_q = flat_heads(k_q), flat_heads(v_q)
     lengths = jnp.asarray([5, S - 7][:B])
     live = jnp.arange(S)[None, :] < lengths[:, None]
     k_s = jnp.where(live[..., None], k_s, 0.0)

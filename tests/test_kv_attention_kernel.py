@@ -13,12 +13,19 @@ from repro.kernels.kv_attention.ops import (
     kv_attention_decode,
     quantize_kv,
 )
-from repro.kernels.kv_attention.ref import kv_attention_ref, kv_attention_xla
+from repro.kernels.kv_attention.kernel import bf16_terms
+from repro.kernels.kv_attention.ref import (
+    block_rows,
+    flat_heads,
+    kv_attention_ref,
+    kv_attention_xla,
+)
 
 
 def _inputs(B, S, Hkv, hd, seed=0, Hq=None, lengths=None):
-    """Random fp K/V quantized per-token/per-head; positions at or past each
-    row's ragged ``length`` get scale 0 (= masked, the op contract)."""
+    """Random fp K/V quantized per-token/per-head into lane-dense payloads;
+    positions at or past each row's ragged ``length`` get scale 0 (=
+    masked, the op contract)."""
     Hq = Hq or Hkv
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, Hq, hd))
@@ -26,6 +33,7 @@ def _inputs(B, S, Hkv, hd, seed=0, Hq=None, lengths=None):
     v = jax.random.normal(ks[2], (B, S, Hkv, hd))
     k_q, k_s = quantize_kv(k)
     v_q, v_s = quantize_kv(v)
+    k_q, v_q = flat_heads(k_q), flat_heads(v_q)
     if lengths is not None:
         valid = jnp.arange(S)[None, :] < jnp.asarray(lengths)[:, None]
         k_s = jnp.where(valid[..., None], k_s, 0.0)
@@ -44,6 +52,35 @@ def test_kernel_matches_ref(B, S, H, hd):
     out = kv_attention(q, k_q, k_s, v_q, v_s, blk=min(256, S),
                        backend="interpret")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("kind", ["normal", "probabilities"])
+def test_bf16_terms_split_exactly(kind):
+    """The attention dots' bf16 terms rebuild an fp32 operand bit for bit
+    (so q·kᵀ and p·v over the bf16-exact int8 payload multiply exactly),
+    for plain values and for softmax weights spread over ~100 binades (down
+    to ~1e-31, whose last term stays a normal fp32); a bf16 operand is its
+    own term."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 512)) * 3.0
+    if kind == "probabilities":
+        x = jnp.exp(-jnp.abs(x) * 5.0) * 0.03       # exp(s − m) · v-scale
+    terms = bf16_terms(x)
+    assert len(terms) == 3 and all(t.dtype == jnp.bfloat16 for t in terms)
+    back = sum(t.astype(jnp.float32) for t in terms)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+    xb = x.astype(jnp.bfloat16)
+    assert bf16_terms(xb) == [xb]
+
+
+@pytest.mark.parametrize("S,width,rows", [
+    (2560, 128, 2560),      # a whole ring fits one 512 KiB block
+    (4096, 128, 4096),
+    (8192, 128, 4096),
+    (5000, 128, 2560),      # two blocks, rounded up to the 128-lane tile
+    (32768, 1024, 512),
+])
+def test_block_rows_by_bytes(S, width, rows):
+    assert block_rows(S, width) == rows
 
 
 def test_block_size_invariance():
@@ -149,6 +186,7 @@ def test_fused_append_decode_matches_manual():
     v_fp = jax.random.normal(ks[1], (B, S, Hkv, hd))
     ck, cks = quantize_kv(k_fp)
     cv, cvs = quantize_kv(v_fp)
+    ck, cv = flat_heads(ck), flat_heads(cv)
     # garbage beyond position 10 — must be masked out by `valid`
     q = jax.random.normal(ks[2], (B, Hq, hd))
     k_new = jax.random.normal(ks[3], (B, 1, Hkv, hd))
@@ -167,10 +205,12 @@ def test_fused_append_decode_matches_manual():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     for a, b in zip(leaves, (mk, mks, mv, mvs)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # the new token landed at idx, quantized exactly once
+    # the new token landed at idx as one lane-dense row, quantized once
     kq10, ks10 = quantize_kv(k_new)
     np.testing.assert_array_equal(np.asarray(leaves[0][:, 10]),
-                                  np.asarray(kq10[:, 0]))
+                                  np.asarray(flat_heads(kq10)[:, 0]))
+    np.testing.assert_array_equal(np.asarray(leaves[1][:, 10]),
+                                  np.asarray(ks10[:, 0]))
 
 
 def test_v_bias_correction_reduces_mean_error():
@@ -185,6 +225,7 @@ def test_v_bias_correction_reduces_mean_error():
     k_q, k_s = quantize_kv(k)
     v_q, v_s = quantize_kv(v)
     v_err = jnp.mean(v_q.astype(jnp.float32) * v_s[..., None] - v, axis=-1)
+    k_q, v_q = flat_heads(k_q), flat_heads(v_q)
 
     fp = _fp_oracle(q, k, v)
     plain = kv_attention_xla(q, k_q, k_s, v_q, v_s)

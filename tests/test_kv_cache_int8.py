@@ -20,6 +20,9 @@ def test_int8_cache_decode_parity(arch):
     full, _ = model.apply(params, toks)
     cache = model.init_cache(2, 24, dtype=jnp.float32)
     assert cache["k"].dtype == jnp.int8 and "k_scale" in cache
+    # lane-dense payload: one cache position of every kv head is one row
+    assert cache["k"].shape[3:] == (cfg.n_kv_heads * cfg.head_dim,)
+    assert cache["k_scale"].shape[3:] == (cfg.n_kv_heads,)
     lp, cache = model.prefill(params, toks[:, :-1], cache)
     ld, cache = model.decode_step(params, toks[:, -1:], cache)
     denom = float(jnp.max(jnp.abs(full[:, -1]))) + 1e-9

@@ -19,7 +19,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_production_mesh
 from repro.quantized.qtensor import QTensor
-from repro.sharding import params_pspecs, serve_cache_pspecs
+from repro.sharding import cache_pspecs, params_pspecs, serve_cache_pspecs
 from repro.sharding.partition import spec_paths
 
 
@@ -122,9 +122,10 @@ def test_train_mode_scale_still_replicates():
 # ------------------------------------------------------------ serving cache
 
 def _kv8_cache(B, *, L=2, S=32, H=2, hd=16, v_err=True):
+    """The serving int8 pool: lane-dense payload [L, B, S, H·hd]."""
     c = {
-        "k": _sds(L, B, S, H, hd, dtype="int8"),
-        "v": _sds(L, B, S, H, hd, dtype="int8"),
+        "k": _sds(L, B, S, H * hd, dtype="int8"),
+        "v": _sds(L, B, S, H * hd, dtype="int8"),
         "k_scale": _sds(L, B, S, H),
         "v_scale": _sds(L, B, S, H),
         "kpos": _sds(B, S, dtype="int32"),
@@ -137,7 +138,7 @@ def _kv8_cache(B, *, L=2, S=32, H=2, hd=16, v_err=True):
 
 def test_serve_cache_slots_shard_over_data():
     spec = serve_cache_pspecs(_kv8_cache(4), MESH)
-    assert spec["k"] == P(None, "data", None, None, None)
+    assert spec["k"] == P(None, "data", None, None)
     assert spec["kpos"] == P("data", None)
     assert spec["pos"] == P("data")
 
@@ -150,14 +151,47 @@ def test_serve_cache_scales_follow_their_cache_tensor():
         assert spec[leaf] == P(None, "data", None, None)
     # a model axis the heads DO divide: payload and scales move together
     spec = serve_cache_pspecs(_kv8_cache(4, H=4), _StubMesh(data=2, model=2))
-    assert spec["k"] == P(None, "data", None, "model", None)
+    assert spec["k"] == P(None, "data", None, "model")
     for leaf in ("k_scale", "v_scale", "v_err"):
         assert spec[leaf] == P(None, "data", None, "model")
 
 
+@pytest.mark.parametrize("H,model,sharded", [
+    (2, 4, False),     # H·hd = 32 divides by 4, but the 2 heads do not
+    (4, 4, True),      # one whole head per shard
+    (8, 4, True),      # two whole heads per shard
+])
+def test_serve_cache_lane_dense_payload_splits_whole_heads(H, model, sharded):
+    """The int8 payload's lane-dense H·hd axis shards over "model" only
+    when the head count divides, so a shard owns contiguous whole heads —
+    never when just H·hd divides — and always with its scales."""
+    spec = serve_cache_pspecs(_kv8_cache(4, H=H),
+                              _StubMesh(data=2, model=model))
+    want = "model" if sharded else None
+    for leaf in ("k", "v"):
+        assert spec[leaf] == P(None, "data", None, want)
+    for leaf in ("k_scale", "v_scale", "v_err"):
+        assert spec[leaf] == P(None, "data", None, want)
+
+
+@pytest.mark.parametrize("H,sharded", [
+    (3, False),        # H·hd = 384 divides by 4, but the 3 heads do not
+    (4, True),         # one whole head per shard
+])
+def test_cache_pspecs_lane_dense_payload_splits_whole_heads(H, sharded):
+    """The generic decode cache specs follow the same whole-head rule: with
+    a ring (30) the model axis cannot split, the int8 payload's lane-dense
+    H·hd axis shards over "model" only when the head count divides."""
+    spec = cache_pspecs(_kv8_cache(4, S=30, H=H, hd=128),
+                        _StubMesh(data=2, model=4), batch=4)
+    want = "model" if sharded else None
+    for leaf in ("k", "v"):
+        assert spec[leaf] == P(None, "data", None, want)
+
+
 def test_serve_cache_non_divisible_slots_replicate():
     spec = serve_cache_pspecs(_kv8_cache(3), MESH)
-    assert spec["k"] == P(None, None, None, None, None)
+    assert spec["k"] == P(None, None, None, None)
     assert spec["kpos"] == P(None, None)
     assert spec["pos"] == P(None)
 

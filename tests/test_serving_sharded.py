@@ -179,7 +179,8 @@ def test_sharded_pool_and_param_placement(tp_artifacts, mesh):
     qm = tp_artifacts["serve-w8a8-kv8-tp"]
     eng = _engine(qm.model, qm.params, qm.cfg, mesh=mesh, num_slots=4)
     cache = eng.pool.cache
-    assert cache["k"].sharding.spec == P(None, "data", None, None, None)
+    assert cache["k"].shape[3] == qm.cfg.n_kv_heads * qm.cfg.head_dim
+    assert cache["k"].sharding.spec == P(None, "data", None, None)
     for leaf in ("k_scale", "v_scale"):
         assert cache[leaf].sharding.spec == P(None, "data", None, None)
     assert cache["kpos"].sharding.spec == P("data", None)

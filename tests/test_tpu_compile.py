@@ -12,6 +12,8 @@ The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -81,7 +83,7 @@ def _quantize_act(M, K):
 
 
 def _kv_attention():
-    cache = [((B, S, HKV, HD), i8), ((B, S, HKV), f32)]
+    cache = [((B, S, HKV * HD), i8), ((B, S, HKV), f32)]
 
     def fn(q, kq, ks, vq, vs):
         return kv_attention(q, kq, ks, vq, vs, backend="pallas")
@@ -90,7 +92,7 @@ def _kv_attention():
 
 
 def _fused_decode(q8):
-    cache = [((B, S, HKV, HD), i8), ((B, S, HKV), f32)]
+    cache = [((B, S, HKV * HD), i8), ((B, S, HKV), f32)]
     new = ((B, 1, HKV, HD), bf16)
 
     def fn(q, kq, ks, vq, vs, kn, vn, idx, valid):
@@ -215,6 +217,51 @@ def w8a16_engine():
                          max_len=288, prefill_chunk=32)
 
 
+@pytest.fixture(scope="module")
+def served_text(one_chip, w8a8_engine, w8a16_engine):
+    """``served_text(recipe, jit)``: the compiled text of one of the
+    engine's served programs for one v5e, every kernel on the Pallas tier,
+    compiled once for the module."""
+    engines = {"w8a8-kv8": w8a8_engine, "w8a16": w8a16_engine}
+    texts = {}
+
+    def get(recipe, jit):
+        if (recipe, jit) not in texts:
+            fn, _, args, kw = engines[recipe].serve_jit_specs()[jit]
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setenv("REPRO_KERNEL_BACKEND", "pallas")
+                texts[(recipe, jit)] = fn.lower(
+                    *_abstract(args, one_chip), **kw).compile().as_text()
+        return texts[(recipe, jit)]
+
+    return get
+
+
+def _ring_copies(text: str, ring: int) -> list:
+    """The s8 ``copy`` instructions of a compiled program with a dim equal
+    to the ring length: relayouts of the int8 KV pool (or of a layer's
+    slice of it) from one layout to another."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= s8\[([0-9,]*)\]\{[^}]*\} copy\(", line)
+        if m and str(ring) in m.group(1).split(","):
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("jit", ["decode_horizon", "prefill_multi"])
+def test_int8_pool_is_read_in_place(jit, served_text, w8a8_engine):
+    """The lane-dense int8 pool ([L, B, S, Hkv·hd]) is in the layout the
+    decode kernel's blocks read, and prefill writes and reads its flat rows:
+    neither program relayouts a layer's slice of the pool. The
+    ``[L, B, S, Hkv, hd]`` pool this replaced cost 4 such copies in each
+    program at this one-layer fixture. (From two layers on, the layer
+    scan's copies of the whole pool appear besides; they are not pinned
+    here.)"""
+    copies = _ring_copies(served_text("w8a8-kv8", jit), w8a8_engine.max_len)
+    assert copies == [], f"{jit}: s8 relayout copies of the KV ring: {copies}"
+
+
 # the Pallas kernels each served program calls, by recipe
 PROGRAM_KERNELS = {
     ("w8a8-kv8", "decode_horizon"): {"fused_decode", "qmatmul_w8a8",
@@ -227,7 +274,7 @@ PROGRAM_KERNELS = {
 
 @pytest.mark.parametrize("recipe,jit", sorted(PROGRAM_KERNELS))
 def test_served_programs_keep_the_names_the_chip_benchmark_reads(
-        recipe, jit, one_chip, w8a8_engine, w8a16_engine, monkeypatch):
+        recipe, jit, served_text):
     """The chip benchmark's trace reduction (``benchmarks/chip/chipbench/
     kernels.py``) finds the decode and prefill programs by their module
     names and each kernel by its op name, ``<kernel>_pallas.<n>``. Compiled
@@ -239,10 +286,7 @@ def test_served_programs_keep_the_names_the_chip_benchmark_reads(
                            / "benchmarks" / "chip"))
     from chipbench import kernels, trace
 
-    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
-    eng = {"w8a8-kv8": w8a8_engine, "w8a16": w8a16_engine}[recipe]
-    fn, _, args, kw = eng.serve_jit_specs()[jit]
-    text = fn.lower(*_abstract(args, one_chip), **kw).compile().as_text()
+    text = served_text(recipe, jit)
     module = text.split(None, 2)[1].rstrip(",")
     assert module == f"jit__{jit}_impl"
     assert kernels.is_decode(module) == (jit == "decode_horizon")
