@@ -46,7 +46,7 @@ def outcome(ctx) -> tuple:
     return len(recs), len(failed), done
 
 
-def judge(ctx, make_params, with_control: bool = False) -> dict:
+def judge(ctx, with_control: bool = False) -> dict:
     attempted, failed, done = outcome(ctx)
     sample = pick(done, int(ctx.cell.mix["sample"]["requests"]), ctx.seed)
     checks = {"failed_requests": {"value": failed, "limit": 0}}
@@ -54,10 +54,9 @@ def judge(ctx, make_params, with_control: bool = False) -> dict:
     n_tok = 0
     if sample:
         t = time.perf_counter()
-        params = make_params()
-        gaps = reference.logit_gaps(
-            params, ctx.config, [(r.spec.prompt, r.tokens) for r in sample],
-            ctx.serving["max_len"], with_control=with_control)
+        gaps = reference.seeded_logit_gaps(
+            ctx.config, ctx.seed, [(r.spec.prompt, r.tokens) for r in sample],
+            ctx.serving["max_len"], with_control=with_control, arch=ctx.arch)
         widest = float(max(g["served"].max() for g in gaps))
         if with_control:
             control = float(max(g["control"].max() for g in gaps))

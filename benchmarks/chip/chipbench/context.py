@@ -25,7 +25,8 @@ class Context:
         self.seed = seed
         self.config = cell.config
         self.serving = cell.config["serving"]
-        self.dims = model.dims(cell.config)
+        self.arch = cell.arch
+        self.dims = model.dims(cell.config, cell.arch)
         self.device_kind = device_kind
         try:
             self.peaks = flops.peaks(device_kind)
@@ -42,6 +43,18 @@ class Context:
 
     def note(self, line: str) -> None:
         self.notes.append(line)
+
+    def decode_step_calls(self) -> dict:
+        """The cell's decode step as its kernels' calls, by the
+        architecture file's count where it gives one."""
+        f = getattr(self.arch, "decode_step_calls", flops.decode_step_calls)
+        return f(self.dims, self.serving["num_slots"],
+                 self.serving["max_len"], self.serving["recipe"])
+
+    def model_flops(self, tokens: float, attended: float,
+                    heads: float) -> float:
+        f = getattr(self.arch, "model_flops", flops.model_flops)
+        return f(self.dims, tokens, attended, heads)
 
     # ---------------------------------------------------- traced window
     def reduce_trace(self, tr, snaps) -> dict:

@@ -66,10 +66,18 @@ def fused_decode(B, S, Hq, Hkv, hd, q_bytes=2):
     return ops, 4 * ring + B * Hq * hd * (q_bytes + 4) + 2 * B * Hkv * hd * 2
 
 
+# the peak each kernel of the default decode plan is held to
+RATE = {"qmatmul_w8a8": "int8", "qmatmul_w8a16": "bf16",
+        "quantize_act": "bf16", "fused_decode": "bf16"}
+
+
 def decode_step_calls(m: dict, B: int, S: int, recipe: str) -> dict:
-    """{kernel: [(ops, bytes) per call]} for one decode step of the whole
-    model at slot batch B and ring S, as the recipe's decode program calls
-    its Pallas kernels (per layer: q, k, v, o, gate, up, down)."""
+    """{kernel: {"rate": the peak its operations are held to, "calls":
+    [(ops, bytes) per call]}} for one decode step of the whole model at
+    slot batch B and ring S, as the recipe's decode program calls its
+    Pallas kernels (per layer: q, k, v, o, gate, up, down). The harness's
+    default, for a dense GQA stack; an architecture file may give its
+    own."""
     D, F, Hq, Hkv, hd, L = m["D"], m["F"], m["Hq"], m["Hkv"], m["hd"], m["L"]
     proj = [(D, Hq * hd), (D, Hkv * hd), (D, Hkv * hd), (Hq * hd, D),
             (D, F), (D, F), (F, D)]
@@ -86,7 +94,7 @@ def decode_step_calls(m: dict, B: int, S: int, recipe: str) -> dict:
         calls = {"qmatmul_w8a16": [qmatmul_w8a16(B, k, n) for k, n in proj]}
     else:
         raise ValueError(f"no decode kernel plan for recipe {recipe!r}")
-    return {k: v * L for k, v in calls.items()}
+    return {k: {"rate": RATE[k], "calls": v * L} for k, v in calls.items()}
 
 
 # ----------------------------------------------------------------- model
@@ -110,6 +118,7 @@ def head_flops(m: dict) -> float:
 def model_flops(m: dict, tokens: float, attended: float,
                 heads: float) -> float:
     """The model's operations for ``tokens`` processed, ``attended`` key
-    positions summed over them, and ``heads`` rows through the head."""
+    positions summed over them, and ``heads`` rows through the head (the
+    harness's default, for a dense GQA stack)."""
     return (tokens * linear_flops_per_token(m) + attention_flops(m, attended)
             + heads * head_flops(m))

@@ -1,13 +1,14 @@
 """A Pallas kernel's share of its roofline in the decode program, read from
-the trace: the least time its calls need (``flops.decode_step_calls``, at
-the chip's peaks) over the device time the trace gives them."""
+the trace: the least time its calls need (the decode step's calls, at the
+peak each kernel's operations are held to; ``flops.decode_step_calls`` or
+the architecture file's own) over the device time the trace gives them."""
 from __future__ import annotations
 
 from . import flops, trace as trace_lib
 
-# the peak each kernel's operations are held to
-RATE = {"qmatmul_w8a8": "int8", "qmatmul_w8a16": "bf16",
-        "quantize_act": "bf16", "fused_decode": "bf16"}
+# the kernels of the default decode plan and their peaks, by which
+# ``tests/test_tpu_compile.py`` names the kernels it looks for
+RATE = flops.RATE
 
 
 def is_decode(module_name: str) -> bool:
@@ -29,13 +30,12 @@ def roofline(ctx, kernel: str):
     p = tr["planes"][0]
     ops = trace_lib.ops_in(tr["ops"][p], tr["modules"].get(p, []), is_decode)
     evs = [e for e in ops if matches(e, kernel)]
-    calls = flops.decode_step_calls(
-        ctx.dims, ctx.serving["num_slots"], ctx.serving["max_len"],
-        ctx.serving["recipe"]).get(kernel)
-    if not evs or not calls or ctx.peaks is None:
+    plan = ctx.decode_step_calls().get(kernel)
+    if not evs or not plan or ctx.peaks is None:
         return None
+    calls = plan["calls"]
     steps = len(evs) / len(calls)
-    bounds = [flops.least_time(o, b, ctx.peaks, RATE[kernel])
+    bounds = [flops.least_time(o, b, ctx.peaks, plan["rate"])
               for o, b in calls]
     least = steps * sum(t for t, _ in bounds)
     took = sum(e.end - e.start for e in evs) * 1e-9

@@ -5,9 +5,12 @@
     <root>/benchmarks/chip/traffic/<mix>.json       one per traffic mix
     <root>/benchmarks/chip/cells/<cell>.json        pinned numbers of one cell
     <root>/benchmarks/chip/metrics/<metric>.py      one reader per metric
+    <root>/benchmarks/chip/chipbench/archs/<model_type>.py
+                                        one per architecture (``model.py``)
 
-A later change adds a configuration, a mix, a cell or a metric by adding
-files and entries; no existing file has to change.
+A later change adds a configuration, a mix, a cell, a metric or an
+architecture by adding files and entries; no existing file has to change.
+A configuration file names its architecture by ``model_type``.
 """
 from __future__ import annotations
 
@@ -49,19 +52,46 @@ class Cell:
     pinned: dict                    # cells/<cell>.json
     end_to_end: list                # [Metric] this cell reports untraced
     per_layer: list                 # [Metric] this cell reports traced
+    arch: Any = None                # chipbench/archs/<model_type>.py
+
+
+def _module(path: Path, prefix: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _reader(bench_dir: Path, name: str) -> Callable:
     path = bench_dir / "metrics" / f"{name}.py"
     if not path.is_file():
         raise LayoutError(f"metric {name!r} has no reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _module(path, "chipbench_metric_", name)
     if not callable(getattr(mod, "read", None)):
         raise LayoutError(f"{path} defines no read(ctx)")
     return mod.read
+
+
+ARCH_NEEDS = ("dims", "program_config", "param_shapes", "init_leaf", "layer",
+              "head")
+_ARCHS: dict = {}
+
+
+def load_arch(model_type: str, bench_dir: Path = BENCH_DIR):
+    """The architecture file of ``model_type``, loaded once per path."""
+    path = bench_dir / "chipbench" / "archs" / f"{model_type}.py"
+    if path not in _ARCHS:
+        if not path.is_file():
+            raise LayoutError(f"model_type {model_type!r} has no "
+                              f"architecture file {path}")
+        mod = _module(path, "chipbench_arch_", str(model_type))
+        missing = [f for f in ARCH_NEEDS if not callable(getattr(mod, f,
+                                                                 None))]
+        if missing:
+            raise LayoutError(f"{path} defines no {', '.join(missing)}")
+        _ARCHS[path] = mod
+    return _ARCHS[path]
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -93,4 +123,5 @@ def load_cell(name: str, root: Path = ROOT,
 
     return Cell(name=name, chips=int(w["chips"]), config=config, mix=mix,
                 pinned=pinned, end_to_end=metrics("end_to_end"),
-                per_layer=metrics("per_layer"))
+                per_layer=metrics("per_layer"),
+                arch=load_arch(config.get("model_type"), bench_dir))

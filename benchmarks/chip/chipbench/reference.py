@@ -1,18 +1,19 @@
-"""The plain reference of a qwen2 configuration, and its control.
+"""The plain reference of a configuration, and its control.
 
 The reference is the published forward pass in float32 ``jax.numpy`` at
 ``highest`` matmul precision: no kernels, no cache, no batching, no
-quantization. It reads the float weights that ``model.make_params`` makes
-from the seed, and nothing the program made.
+quantization. Its block is the architecture file's ``layer`` and its head
+the file's ``head`` (``model.py``); this module gives them the operations
+(``Ops``): RMSNorm, projections, rotate-half RoPE and causal GQA attention.
 
     x = embed[tokens]
-    per layer:  h = rms(x) * w_attn_norm
-                q, k, v = h Wq + bq, h Wk + bk, h Wv + bv      (GQA)
-                q, k = rope(q), rope(k)          (rotate-half, theta)
-                x = x + softmax(q k^T / sqrt(hd) + causal) v Wo + bo
-                h = rms(x) * w_mlp_norm
-                x = x + (silu(h Wg) * (h Wu)) Wd + bd
-    logits = (rms(x) * w_final_norm) embed^T                  (tied head)
+    x = layer(x, block) for every block
+    logits = (rms(x) * w_final_norm) head
+
+It reads the float weights that ``model.py`` makes from the seed, and
+nothing the program made: all at once, or, for a configuration made
+layer by layer, one layer at a time, every sampled sequence through a
+layer before the next layer is made.
 
 The control is the same pass with the configuration's stated precisions
 stepped one down (``control`` in the configuration file): weights quantized
@@ -41,68 +42,84 @@ def _fq(x, bits, axis):
     return jnp.clip(jnp.round(x / s), -qmax, qmax) * s
 
 
-def _forward(params, tokens, *, m, prec):
-    """tokens [T] -> final normed hidden [T, D] (float32)."""
-    import jax
-    import jax.numpy as jnp
+class Ops:
+    """The reference's operations over one sequence of ``T`` positions, at
+    the precisions ``prec`` (empty for the reference, ``control`` for the
+    control)."""
 
-    hp = jax.lax.Precision.HIGHEST
-    act_bits = prec.get("activations")
-    kv_bits = prec.get("kv_cache")
+    def __init__(self, m: dict, prec: dict, T: int):
+        import jax
+        import jax.numpy as jnp
 
-    def rms(x, w):
+        self.m, self.T = m, T
+        self.hp = jax.lax.Precision.HIGHEST
+        self.act_bits = prec.get("activations")
+        self.kv_bits = prec.get("kv_cache")
+        half = m["hd"] // 2
+        freqs = 1.0 / (m["theta"] ** (jnp.arange(half, dtype=jnp.float32)
+                                      / half))
+        ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs
+        self.cos, self.sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+        self.causal = jnp.tril(jnp.ones((T, T), bool))
+
+    def rms(self, x, w):
+        import jax
+        import jax.numpy as jnp
+
         return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                                 + m["eps"]) * w
+                                 + self.m["eps"]) * w
 
-    def lin(x, w, b=None):
-        if isinstance(act_bits, int):
-            x = _fq(x, act_bits, -1)
-        y = jnp.dot(x, w, precision=hp)
+    def lin(self, x, w, b=None):
+        import jax.numpy as jnp
+
+        if isinstance(self.act_bits, int):
+            x = _fq(x, self.act_bits, -1)
+        y = jnp.dot(x, w, precision=self.hp)
         return y if b is None else y + b
 
-    T = tokens.shape[0]
-    Hq, Hkv, hd = m["Hq"], m["Hkv"], m["hd"]
-    half = hd // 2
-    freqs = 1.0 / (m["theta"] ** (jnp.arange(half, dtype=jnp.float32)
-                                  / half))
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    def rope(self, x):                              # [T, H, hd]
+        import jax.numpy as jnp
 
-    def rope(x):                                   # [T, H, hd]
+        half = self.m["hd"] // 2
         x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+        return jnp.concatenate([x1 * self.cos - x2 * self.sin,
+                                x2 * self.cos + x1 * self.sin], -1)
 
-    causal = jnp.tril(jnp.ones((T, T), bool))
+    def attend(self, q, k, v):
+        """Causal GQA attention of q [T, Hq, hd] over k, v [T, Hkv, hd]
+        (fake-quantized per position and head at ``kv_cache`` bits), as
+        [T, Hq * hd]."""
+        import jax
+        import jax.numpy as jnp
 
-    def layer(x, p):
-        a = p["attn"]
-        h = rms(x, p["attn_norm"]["w"])
-        q = rope(lin(h, a["wq"], a["bq"]).reshape(T, Hq, hd))
-        k = rope(lin(h, a["wk"], a["bk"]).reshape(T, Hkv, hd))
-        v = lin(h, a["wv"], a["bv"]).reshape(T, Hkv, hd)
-        if isinstance(kv_bits, int):
-            k, v = _fq(k, kv_bits, -1), _fq(v, kv_bits, -1)
+        m, T = self.m, self.T
+        Hq, Hkv, hd = m["Hq"], m["Hkv"], m["hd"]
+        if isinstance(self.kv_bits, int):
+            k, v = _fq(k, self.kv_bits, -1), _fq(v, self.kv_bits, -1)
         k = jnp.repeat(k, Hq // Hkv, axis=1)
         v = jnp.repeat(v, Hq // Hkv, axis=1)
-        s = jnp.einsum("thd,shd->hts", q, k, precision=hp) / jnp.sqrt(
+        s = jnp.einsum("thd,shd->hts", q, k, precision=self.hp) / jnp.sqrt(
             jnp.float32(hd))
-        s = jnp.where(causal[None], s, -jnp.inf)
-        o = jnp.einsum("hts,shd->thd", jax.nn.softmax(s, -1), v,
-                       precision=hp).reshape(T, Hq * hd)
-        x = x + lin(o, a["wo"], a["bo"])
-        f = p["mlp"]
-        h = rms(x, p["mlp_norm"]["w"])
-        g = jax.nn.silu(lin(h, f["wg"])) * lin(h, f["wu"])
-        return x + lin(g, f["wd"], f["bd"]), None
-
-    x = params["embed"][tokens]
-    x, _ = jax.lax.scan(layer, x, params["blocks"])
-    return rms(x, params["final_norm"]["w"])
+        s = jnp.where(self.causal[None], s, -jnp.inf)
+        return jnp.einsum("hts,shd->thd", jax.nn.softmax(s, -1), v,
+                          precision=self.hp).reshape(T, Hq * hd)
 
 
-def _head(params):
-    w = params.get("lm_head")
-    return params["embed"].T if w is None else w
+def _layers(arch, f: Ops, x, blocks):
+    import jax
+
+    def body(x, p):
+        return arch.layer(x, p, f), None
+
+    x, _ = jax.lax.scan(body, x, blocks)
+    return x
+
+
+def _forward(arch, params, tokens, *, m, prec):
+    """tokens [T] -> final normed hidden [T, D] (float32)."""
+    f = Ops(m, prec, tokens.shape[0])
+    x = _layers(arch, f, params["embed"][tokens], params["blocks"])
+    return f.rms(x, params["final_norm"]["w"])
 
 
 def _quantize_weights(params, bits):
@@ -118,57 +135,89 @@ def _quantize_weights(params, bits):
     return jax.tree_util.tree_map_with_path(q, params)
 
 
-@functools.lru_cache(maxsize=None)
-def _compiled(m_items: tuple, prec_items: tuple, with_control: bool):
-    """jit of (params, tokens [T], targets [T]) -> per position:
-    reference best logit, reference logit of the target, and (with the
-    control) the reference logit of the control's first choice."""
+def _logits(arch, params, cparams, h, hc, targets, ctl):
+    """Per position: the reference's best logit, the reference logit of the
+    target, and (with the control's final hiddens ``hc``) the reference
+    logit of the control's first choice."""
     import jax
     import jax.numpy as jnp
 
-    m, ctl = dict(m_items), dict(prec_items)
     hp = jax.lax.Precision.HIGHEST
+    head = arch.head(params)
+    if hc is not None:
+        cdt = jnp.dtype(ctl.get("head", "float32"))
+        chead = arch.head(cparams).astype(cdt)
+    T = h.shape[0]
+    best, at_t, at_c = [], [], []
+    for lo in range(0, T, BLOCK):
+        lg = jnp.dot(h[lo:lo + BLOCK], head, precision=hp)
+        best.append(jnp.max(lg, -1))
+        at_t.append(jnp.take_along_axis(
+            lg, targets[lo:lo + BLOCK, None], -1)[:, 0])
+        if hc is not None:
+            lc = jnp.dot(hc[lo:lo + BLOCK].astype(cdt), chead,
+                         preferred_element_type=jnp.float32)
+            pick = jnp.argmax(lc, -1)
+            at_c.append(jnp.take_along_axis(lg, pick[:, None], -1)[:, 0])
+    out = (jnp.concatenate(best), jnp.concatenate(at_t))
+    return out + ((jnp.concatenate(at_c),) if hc is not None else ())
+
+
+def _control_params(params, ctl):
+    if isinstance(ctl.get("weights"), int):
+        return _quantize_weights(params, ctl["weights"])
+    return params
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled(arch, m_items: tuple, prec_items: tuple, with_control: bool):
+    """jit of (params, tokens [T], targets [T]) -> ``_logits``'s rows, over
+    the whole model at once."""
+    import jax
+
+    m, ctl = dict(m_items), dict(prec_items)
 
     def fn(params, tokens, targets):
-        h = _forward(params, tokens, m=m, prec={})
-        head = _head(params)
+        h = _forward(arch, params, tokens, m=m, prec={})
+        cparams, hc = params, None
         if with_control:
-            cparams = params
-            if isinstance(ctl.get("weights"), int):
-                cparams = _quantize_weights(params, ctl["weights"])
-            hc = _forward(cparams, tokens, m=m, prec=ctl)
-            cdt = jnp.dtype(ctl.get("head", "float32"))
-            chead = _head(cparams).astype(cdt)
-        T = tokens.shape[0]
-        best, at_t, at_c = [], [], []
-        for lo in range(0, T, BLOCK):
-            lg = jnp.dot(h[lo:lo + BLOCK], head, precision=hp)
-            best.append(jnp.max(lg, -1))
-            at_t.append(jnp.take_along_axis(
-                lg, targets[lo:lo + BLOCK, None], -1)[:, 0])
-            if with_control:
-                lc = jnp.dot(hc[lo:lo + BLOCK].astype(cdt), chead,
-                             preferred_element_type=jnp.float32)
-                pick = jnp.argmax(lc, -1)
-                at_c.append(jnp.take_along_axis(lg, pick[:, None], -1)[:, 0])
-        out = (jnp.concatenate(best), jnp.concatenate(at_t))
-        return out + ((jnp.concatenate(at_c),) if with_control else ())
+            cparams = _control_params(params, ctl)
+            hc = _forward(arch, cparams, tokens, m=m, prec=ctl)
+        return _logits(arch, params, cparams, h, hc, targets, ctl)
 
     return jax.jit(fn)
 
 
-def logit_gaps(params, config: dict, seqs: list, pad_to: int,
-               with_control: bool = False) -> list:
-    """For each (prompt, served) pair: the gaps, at every served position,
-    by which the served token's reference logit lies below the reference's
-    best (and, with the control, the gap of the control's first choice).
-    Sequences are padded to ``pad_to`` so that one program serves all."""
+@functools.lru_cache(maxsize=None)
+def _staged(arch, m_items: tuple, prec_items: tuple, T: int):
+    """The jits of a configuration made layer by layer: the embedding, a
+    layer (the reference's and the control's), and the head."""
+    import jax
+
+    m, ctl = dict(m_items), dict(prec_items)
+
+    def layers(blocks, x, control):
+        f = Ops(m, ctl if control else {}, T)
+        if control:
+            blocks = _control_params(blocks, ctl)
+        return _layers(arch, f, x, blocks)
+
+    def head(outer, x, xc, targets):
+        f = Ops(m, {}, T)
+        h = f.rms(x, outer["final_norm"]["w"])
+        hc = None if xc is None else f.rms(xc, outer["final_norm"]["w"])
+        return _logits(arch, outer, _control_params(outer, ctl), h, hc,
+                       targets, ctl)
+
+    return (jax.jit(lambda outer, tokens: outer["embed"][tokens]),
+            jax.jit(layers, static_argnames="control"), jax.jit(head))
+
+
+def _padded(seqs: list, pad_to: int):
+    """(prompt + served[:-1], served at the positions that predict it)
+    padded to ``pad_to``, and the served span of each."""
     import numpy as np
 
-    m = model_lib.dims(config)
-    fn = _compiled(tuple(sorted(m.items())),
-                   tuple(sorted(config.get("control", {}).items())),
-                   with_control)
     out = []
     for prompt, served in seqs:
         P, G = len(prompt), len(served)
@@ -177,9 +226,56 @@ def logit_gaps(params, config: dict, seqs: list, pad_to: int,
         seq[P:P + G - 1] = served[:-1]
         tgt = np.zeros(pad_to, np.int32)
         tgt[P - 1:P - 1 + G] = served
-        res = [np.asarray(a)[P - 1:P - 1 + G] for a in fn(params, seq, tgt)]
-        gaps = {"served": res[0] - res[1]}
-        if with_control:
-            gaps["control"] = res[0] - res[2]
-        out.append(gaps)
+        out.append((seq, tgt, slice(P - 1, P - 1 + G)))
     return out
+
+
+def _as_gaps(res, span, with_control) -> dict:
+    import numpy as np
+
+    res = [np.asarray(a)[span] for a in res]
+    gaps = {"served": res[0] - res[1]}
+    if with_control:
+        gaps["control"] = res[0] - res[2]
+    return gaps
+
+
+def logit_gaps(params, config: dict, seqs: list, pad_to: int,
+               with_control: bool = False, arch=None) -> list:
+    """For each (prompt, served) pair: the gaps, at every served position,
+    by which the served token's reference logit lies below the reference's
+    best (and, with the control, the gap of the control's first choice).
+    Sequences are padded to ``pad_to`` so that one program serves all."""
+    arch = model_lib.arch_of(config, arch)
+    m = model_lib.dims(config, arch)
+    fn = _compiled(arch, tuple(sorted(m.items())),
+                   tuple(sorted(config.get("control", {}).items())),
+                   with_control)
+    return [_as_gaps(fn(params, seq, tgt), span, with_control)
+            for seq, tgt, span in _padded(seqs, pad_to)]
+
+
+def seeded_logit_gaps(config: dict, seed: int, seqs: list, pad_to: int,
+                      with_control: bool = False, arch=None) -> list:
+    """``logit_gaps`` over the weights that ``seed`` makes: made whole, or,
+    for a configuration made layer by layer, one layer at a time."""
+    arch = model_lib.arch_of(config, arch)
+    if not model_lib.by_layer(config):
+        return logit_gaps(model_lib.make_params(config, seed, arch), config,
+                          seqs, pad_to, with_control, arch)
+    m = model_lib.dims(config, arch)
+    embed, layers, head = _staged(
+        arch, tuple(sorted(m.items())),
+        tuple(sorted(config.get("control", {}).items())), pad_to)
+    outer = model_lib.make_outer(config, seed, arch)
+    rows = _padded(seqs, pad_to)
+    xs = [embed(outer, seq) for seq, _, _ in rows]
+    xcs = list(xs) if with_control else [None] * len(xs)
+    for i in range(m["L"]):
+        blocks = model_lib.make_layers(config, seed, i, 1, arch)
+        xs = [layers(blocks, x, control=False) for x in xs]
+        if with_control:
+            xcs = [layers(blocks, x, control=True) for x in xcs]
+        del blocks
+    return [_as_gaps(head(outer, x, xc, tgt), span, with_control)
+            for x, xc, (_, tgt, span) in zip(xs, xcs, rows)]

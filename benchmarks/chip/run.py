@@ -25,12 +25,15 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from typing import Optional  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
@@ -98,30 +101,178 @@ def _memory(devs) -> dict:
             for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
 
 
-def pool_bytes(ctx) -> int:
-    """Bytes of the engine's KV pool: per slot position, K and V of every
-    layer (int8 with a float32 scale per head, or the compute type) and the
+def dense_pool_bytes_per_position(m: dict, serving: dict) -> int:
+    """The dense-GQA pool's bytes per slot position: K and V of every layer
+    (int8 with a float32 scale per head, or the compute type) and the
     position's int32 bookkeeping."""
-    m, s = ctx.dims, ctx.serving
-    if s["kv_bits"] == 8:
+    if serving["kv_bits"] == 8:
         per = m["L"] * 2 * m["Hkv"] * (m["hd"] + 4)
     else:
         per = m["L"] * 2 * m["Hkv"] * m["hd"] * 2
-    return s["num_slots"] * s["max_len"] * (per + 4)
+    return per + 4
 
 
-def _check_pool_fits(ctx, mem: dict) -> None:
-    need = pool_bytes(ctx)
+def pool_bytes(ctx) -> int:
+    """Bytes of the engine's KV pool, by the architecture file's count per
+    position where it gives one."""
+    per = getattr(ctx.arch, "pool_bytes_per_position",
+                  dense_pool_bytes_per_position)
+    s = ctx.serving
+    return s["num_slots"] * s["max_len"] * per(ctx.dims, s)
+
+
+def pool_share(prog_model, serving: dict, mesh) -> float:
+    """The fullest device's share of the KV pool's bytes: 1 on one chip;
+    on a mesh, read off the program's own serve-mode cache shardings, under
+    which a leaf whose slots or heads do not divide stays whole on every
+    device."""
+    if mesh is None:
+        return 1.0
+    import jax
+    import jax.numpy as jnp
+    from repro.sharding import named_shardings, serve_cache_pspecs
+
+    cache = jax.eval_shape(lambda: prog_model.init_cache(
+        serving["num_slots"], serving["max_len"],
+        dtype=jnp.dtype(prog_model.cfg.dtype), per_slot=True,
+        kv_bits=serving["kv_bits"]))
+    placed = named_shardings(serve_cache_pspecs(cache, mesh), mesh)
+
+    def nbytes(shape, leaf):
+        return math.prod(shape) * leaf.dtype.itemsize
+
+    whole = sum(nbytes(a.shape, a) for a in jax.tree.leaves(cache))
+    part = sum(jax.tree.leaves(jax.tree.map(
+        lambda a, sh: nbytes(sh.shard_shape(a.shape), a), cache, placed)))
+    return part / whole
+
+
+def _check_pool_fits(ctx, mem: dict, share: float) -> None:
+    """The pool, at the fullest device's ``share`` of it, has to fit in
+    half of that device's free memory: the warm-up holds a second copy."""
+    need = math.ceil(pool_bytes(ctx) * share)
     free = mem["bytes_limit"] - mem["bytes_in_use"]
     log(f"memory after quantize: bytes_limit {mem['bytes_limit']}, "
         f"bytes_in_use {mem['bytes_in_use']}, peak {mem['peak_bytes_in_use']}"
         f"; pool of {ctx.serving['num_slots']} slots x "
-        f"{ctx.serving['max_len']} positions needs {need} bytes, half of "
-        f"the free {free} is {free // 2}")
+        f"{ctx.serving['max_len']} positions needs {need} bytes a device, "
+        f"half of the free {free} is {free // 2}")
     if mem["bytes_limit"] and need > free // 2:
         raise BenchError(
-            f"the KV pool ({need} bytes) does not fit in half of the free "
-            f"device memory ({free} bytes): the warm-up holds a second copy")
+            f"the KV pool ({need} bytes a device) does not fit in half of the "
+            f"free device memory ({free} bytes): the warm-up holds a second "
+            "copy")
+
+
+def _mesh(ctx, devs):
+    """The cell's mesh over its devices, from the configuration's
+    ``serving.mesh`` ({"data": d, "model": m}), or None on one chip."""
+    shape = ctx.serving.get("mesh")
+    if len(devs) == 1 and not shape:
+        return None
+    if not shape or shape["data"] * shape["model"] != len(devs):
+        raise BenchError(f"the cell runs on {len(devs)} chips; its "
+                         f"configuration's serving.mesh is {shape!r}")
+    import numpy as np
+    from jax.sharding import Mesh
+
+    grid = np.array(devs).reshape(shape["data"], shape["model"])
+    return Mesh(grid, ("data", "model"))
+
+
+def _placement(ctx, mesh, ModelConfig) -> Optional[dict]:
+    """{leaf path: sharding} of the float32 weights under the program's own
+    serve-mode partition specs, so that they are made where they are
+    served; None without a mesh."""
+    if mesh is None:
+        return None
+    import jax
+    from repro.sharding import named_shardings, params_pspecs
+
+    from chipbench import model
+
+    shapes = {p: jax.ShapeDtypeStruct(s, "float32")
+              for p, s in model.param_shapes(ctx.config, ctx.arch).items()}
+    heads = {"n_q": ctx.dims["Hq"], "n_kv": ctx.dims["Hkv"]}
+    specs = named_shardings(
+        params_pspecs(model.nest(shapes), mesh, heads, mode="serve"), mesh)
+    flat = jax.tree_util.tree_flatten_with_path(specs)[0]
+    return {"/".join(k.key for k in path): s for path, s in flat}
+
+
+def _quantize(ctx, seed, repro, build_model, ModelConfig, placement):
+    """The quantized model, and the seconds spent making weights (None
+    where they are made layer by layer, between the layers' quantizing).
+
+    Without ``weights.by_layer``: every float32 weight in one call, then
+    the recipe over the whole model. With it: the leaves outside the layers
+    once, then for each layer its float32 weights from the seed, the recipe
+    over that layer alone, and only the result kept, written into its place
+    in the stacked layers. The serve recipes' stages (fold_norm, CLE,
+    bias_absorb, pack, kv_cache) act within a layer, so layer by layer
+    quantizes exactly as the whole model does
+    (``tests/test_chipbench_groups.py``)."""
+    import dataclasses
+
+    import jax
+
+    from chipbench import model
+
+    cfg, arch, recipe = ctx.config, ctx.arch, ctx.serving["recipe"]
+    whole = model.program_config(cfg, ModelConfig, arch)
+    if not model.by_layer(cfg):
+        t = time.perf_counter()
+        params = model.make_params(cfg, seed, arch, placement)
+        jax.block_until_ready(params)
+        t_weights = time.perf_counter() - t
+        qm = repro.quantize(build_model(whole), params=params, recipe=recipe)
+        jax.block_until_ready(qm.params)
+        del params
+        return qm, t_weights
+    on_outer, inner = model.split_shardings(placement)
+    outer = model.make_outer(cfg, seed, arch, on_outer)
+    one = build_model(dataclasses.replace(whole, n_layers=1))
+    stacked = qm = None
+    for i in range(whole.n_layers):
+        qm = repro.quantize(one, params={
+            **outer, "blocks": model.make_layers(cfg, seed, i, 1, arch, inner)},
+            recipe=recipe)
+        if stacked is None:
+            stacked = _zeros_stacked(qm.params["blocks"], whole.n_layers)
+        stacked = _layer_writer()(stacked, qm.params["blocks"], i)
+        jax.block_until_ready(stacked)
+        mem = _memory(jax.devices())
+        log(f"layer {i} quantized; bytes_in_use {mem['bytes_in_use']}, "
+            f"peak {mem['peak_bytes_in_use']}")
+    cfg_q = dataclasses.replace(qm.cfg, n_layers=whole.n_layers)
+    params = {**qm.params, "blocks": stacked}
+    return dataclasses.replace(qm, cfg=cfg_q, model=build_model(cfg_q),
+                               params=params), None
+
+
+def _zeros_stacked(part, L: int):
+    """Zeros of ``part``'s leaves with L layers, where each leaf lies."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(
+        lambda p: jax.tree.map(
+            lambda a: jnp.zeros((L,) + a.shape[1:], a.dtype), p),
+        out_shardings=jax.tree.map(lambda a: a.sharding, part))(part)
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_writer():
+    """jit of (stacked, part, lo) -> stacked with ``part`` written at layer
+    ``lo``, in place (the stacked layers are donated)."""
+    import jax
+
+    def put(stacked, part, lo):
+        return jax.tree.map(
+            lambda s, p: jax.lax.dynamic_update_slice_in_dim(s, p, lo, 0),
+            stacked, part)
+
+    return jax.jit(put, donate_argnums=0)
 
 
 class Setup:
@@ -145,45 +296,40 @@ def prepare(args, root=layout.ROOT, bench_dir=None,
     except ImportError as e:
         raise BenchError(f"the program is not in this checkout: {e}") from None
     _configure_jax(root)
-    import jax
     from repro.models import build_model
     from repro.models.config import ModelConfig
     from repro.serving import ServingEngine
     from repro.serving.scheduler import Request
 
-    from chipbench import model
-
     devs = _devices(cell.chips, require_tpu)[:cell.chips]
     ctx = Context(cell, args.seed, devs[0].device_kind,
                   require_peaks=require_tpu)
     s = ctx.serving
+    mesh = _mesh(ctx, devs)
     log(f"cell {cell.name}: {ctx.config['name']} recipe {s['recipe']}, "
         f"{s['num_slots']} slots x {s['max_len']}, seed {args.seed}, "
         f"{args.seconds} s, trace {args.trace}, device "
-        f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}")
+        f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
+        f"{'' if mesh is None else f', mesh {dict(mesh.shape)}'}")
 
     t = time.perf_counter()
-    prog_model = build_model(model.program_config(ctx.config, ModelConfig))
-    params = model.make_params(ctx.config, args.seed)
-    jax.block_until_ready(params)
-    t_weights = time.perf_counter() - t
-    t = time.perf_counter()
-    qm = repro.quantize(prog_model, params=params, recipe=s["recipe"])
-    jax.block_until_ready(qm.params)
-    del params
+    qm, t_weights = _quantize(ctx, args.seed, repro, build_model, ModelConfig,
+                              _placement(ctx, mesh, ModelConfig))
     gc.collect()
     t_quant = time.perf_counter() - t
-    _check_pool_fits(ctx, _memory(devs))
+    _check_pool_fits(ctx, _memory(devs), pool_share(qm.model, s, mesh))
     t = time.perf_counter()
     engine = ServingEngine.from_quantized(
         qm, num_slots=s["num_slots"], max_len=s["max_len"],
         prefill_chunk=s["prefill_chunk"], decode_horizon=s["decode_horizon"],
-        kv_bits=s["kv_bits"])
+        kv_bits=s["kv_bits"], mesh=mesh)
     del qm
     engine.warmup()
     t_engine = time.perf_counter() - t
-    log(f"set-up split: weights {t_weights:.3f} s, quantize {t_quant:.3f} s,"
-        f" engine build + warmup {t_engine:.3f} s")
+    made = (f"weights and quantize layer by layer {t_quant:.3f} s"
+            if t_weights is None else f"weights {t_weights:.3f} s, quantize "
+            f"{t_quant - t_weights:.3f} s")
+    log(f"set-up split: {made}, engine build + warmup {t_engine:.3f} s")
     return Setup(cell, ctx, engine, devs, Request)
 
 
@@ -194,7 +340,7 @@ def run_cell(args, root=layout.ROOT, bench_dir=None,
     su = prepare(args, root, bench_dir, require_tpu)
     import jax
 
-    from chipbench import check, client as client_lib, model, traffic
+    from chipbench import check, client as client_lib, traffic
 
     cell, ctx, engine, devs = su.cell, su.ctx, su.engine, su.devs
     s = ctx.serving
@@ -250,9 +396,7 @@ def run_cell(args, root=layout.ROOT, bench_dir=None,
     del engine, cl
     gc.collect()
     shown = len(ctx.notes)
-    verdict = check.judge(ctx, lambda: model.make_params(ctx.config,
-                                                         args.seed),
-                          with_control=control)
+    verdict = check.judge(ctx, with_control=control)
     for line in ctx.notes[shown:]:
         log(line)
     result = {

@@ -27,6 +27,12 @@ TINY_CONFIG = {
     "control": {"weights": 4, "activations": 4, "kv_cache": 4,
                 "head": "bfloat16"},
 }
+# a mistral-shaped configuration (no biases, untied head, head_dim not
+# hidden / heads) made and quantized layer by layer
+TINY_MISTRAL = dict(
+    TINY_CONFIG, name="tiny-mistral", model_type="mistral", head_dim=32,
+    num_hidden_layers=3, tie_word_embeddings=False,
+    weights={"embed_std": 0.02, "norm_std": 0.1, "by_layer": True})
 TINY_MIXES = {
     "closed": {"loop": "closed", "backlog_per_slot": 2,
                "prompt": {"dist": "lognormal", "median": 12, "sigma": 0.5,
@@ -45,13 +51,16 @@ TINY_MIXES = {
 
 def make_tiny_checkout(root: Path, gap_limit: float = 10.0,
                        rate: float = 4.0) -> Path:
-    """A checkout holding BENCHMARK.json and the benchmark's files, with the
-    tiny configuration and its two cells (``tiny.closed``, ``tiny.open``);
-    the program is linked in. Returns the benchmark's directory."""
+    """A checkout holding BENCHMARK.json and the benchmark's files (metric
+    readers and architecture files), with the tiny configuration and its
+    two cells (``tiny.closed``, ``tiny.open``); the program is linked in.
+    Returns the benchmark's directory."""
     bench = root / "benchmarks" / "chip"
     for sub in ("configs", "traffic", "cells"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
     shutil.copytree(BENCH / "metrics", bench / "metrics")
+    shutil.copytree(BENCH / "chipbench" / "archs", bench / "chipbench" / "archs",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(REPO / "src", root / "src")
     (bench / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))
     cells = []

@@ -58,10 +58,15 @@ def test_fused_decode_whole_ring():
 
 def test_decode_step_calls_cover_every_kernel_of_the_recipe():
     calls = flops.decode_step_calls(M, 128, 2560, "serve-w8a8-kv8")
-    assert {k: len(v) for k, v in calls.items()} == {
+    assert {k: len(v["calls"]) for k, v in calls.items()} == {
         "qmatmul_w8a8": 7 * 24, "quantize_act": 3 * 24, "fused_decode": 24}
-    assert list(flops.decode_step_calls(M, 128, 2560, "serve-w8a16")) == [
-        "qmatmul_w8a16"]
+    # each kernel's peak comes with its calls
+    assert {k: v["rate"] for k, v in calls.items()} == {
+        "qmatmul_w8a8": "int8", "quantize_act": "bf16",
+        "fused_decode": "bf16"}
+    w8a16 = flops.decode_step_calls(M, 128, 2560, "serve-w8a16")
+    assert list(w8a16) == ["qmatmul_w8a16"]
+    assert w8a16["qmatmul_w8a16"]["rate"] == "bf16"
 
 
 def test_model_flops_per_token():
