@@ -144,8 +144,35 @@ def op_name(event) -> str:
     return event.name.split(" = ", 1)[0].lstrip("%")
 
 
+# an array shape in HLO text: its element type and its dimensions, as in
+# ``bf16[32,151936]{1,0:T(8,128)(2,1)}`` (a dynamic one as ``<=32``)
+_SHAPE = re.compile(r"\b(?:pred|token|[a-z]+\d+[a-z0-9]*)\[([<=\d,]*)\]")
+
+
+def shapes(text: str) -> list:
+    """The shapes in an op's HLO text, each a tuple of its dimensions:
+    every array of a tuple, the result's and the operands'; layouts and
+    instance numbers are no shapes."""
+    return [tuple(int(d.lstrip("<=")) for d in dims.split(",") if d)
+            for dims in _SHAPE.findall(text)]
+
+
+def has_dim(event, n: int) -> bool:
+    """Whether one of a device op's shapes has ``n`` as a whole dimension."""
+    return any(n in s for s in shapes(event.name))
+
+
 # ops that hold other ops: their time is their bodies'
 CONTAINERS = ("while", "conditional", "call")
+
+
+def kind(event) -> str:
+    """An op's name with its ``.N`` instance number dropped."""
+    return re.sub(r"\.\d+$", "", op_name(event))
+
+
+def is_container(event) -> bool:
+    return kind(event) in CONTAINERS
 
 
 def top_ops(ops, n=10) -> list:
@@ -153,7 +180,7 @@ def top_ops(ops, n=10) -> list:
     ``.N`` instance number dropped, leaving out the containers."""
     tot = {}
     for e in ops:
-        name = re.sub(r"\.\d+$", "", op_name(e))
+        name = kind(e)
         if name in CONTAINERS:
             continue
         tot[name] = tot.get(name, 0.0) + (e.end - e.start)

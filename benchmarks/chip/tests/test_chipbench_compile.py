@@ -54,15 +54,18 @@ def one_layer_engine():
     """One layer at its published widths, made and quantized as the
     benchmark does, with a 4096-row vocabulary (the head is no kernel) and
     8 slots over a 288-position ring."""
+    import dataclasses
+
     import repro
     from repro.models import build_model
     from repro.models.config import ModelConfig
     from repro.serving import ServingEngine
 
-    # the epsilon is no shape: the program's own 1e-6 stands in for the
-    # published 1e-5 where the program has no norm_eps
-    config = dict(MISTRAL_NEMO_12B, num_hidden_layers=1, vocab_size=4096,
-                  rms_norm_eps=1e-6)
+    # the published 1e-5 where the program takes an epsilon; else the
+    # program's own 1e-6 stands in for it (the epsilon is no shape)
+    config = dict(MISTRAL_NEMO_12B, num_hidden_layers=1, vocab_size=4096)
+    if "norm_eps" not in {f.name for f in dataclasses.fields(ModelConfig)}:
+        config["rms_norm_eps"] = 1e-6
     m = model.dims(config)
     assert (m["D"], m["Hq"], m["Hkv"], m["hd"], m["F"]) == (
         5120, 32, 8, 128, 14336)

@@ -247,6 +247,7 @@ RECORDED = {
         "sched.occupancy.batch": 100.0,
         "step.decode_ms.batch": 34.678733,
         "step.mfu.batch": 1.6464128069012272,
+        "step.vocab_ms.batch": 1.624866,
         "qmatmul_w8a8_roofline": 35.096712286345934,
         "fused_decode_roofline": 7.764371140870386,
         "device.idle_share.batch": 1.0836252267149904,
@@ -261,6 +262,7 @@ RECORDED = {
         "sched.occupancy.batch": 100.0,
         "step.decode_ms.batch": 38.842853375,
         "step.mfu.batch": 0.7419049951074309,
+        "step.vocab_ms.batch": 0.536937625,
         "qmatmul_w8a16_roofline": 36.38206746771734,
         "device.idle_share.batch": 0.1287186993386813,
         "device.hbm_gb.batch": 1.4737,
